@@ -244,3 +244,4 @@ class BeaconProcessor:
             self._work_ready.notify_all()
         for w in self._workers:
             w.join(timeout=5)
+        self._timer.join(timeout=1)

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import copy
 import json
+import os
 import threading
 from dataclasses import dataclass, field
 from typing import Optional
@@ -70,10 +71,9 @@ class ClientConfig:
     scheduler_bulk_resume_headroom: Optional[float] = None
     # AOT warmup + warm-shape routing + persistent executable caching for
     # the staged device pipeline (compile_service/); only effective with
-    # bls_backend="tpu". None cache dir = LIGHTHOUSE_TPU_COMPILE_CACHE_DIR
-    # env (unset = no persistent cache); empty rungs = the default ladder.
+    # bls_backend="tpu". The cache lives in JAX_COMPILATION_CACHE_DIR,
+    # else <repo>/.jax_cache; empty rungs = the default ladder.
     compile_service: bool = True
-    compile_cache_dir: Optional[str] = None
     compile_rungs: tuple = ()
     # device-resident validator pubkey table (crypto/device/key_table.py,
     # ISSUE 10): uploaded once from the chain's ValidatorPubkeyCache,
@@ -289,11 +289,15 @@ class ClientBuilder:
         self.genesis_state = state
         return self
 
-    def with_interop_genesis(self, validator_count: int, genesis_time: int = 0):
+    def with_interop_genesis(
+        self, validator_count: int, genesis_time: int = 0,
+        fork_name: str = "phase0",
+    ):
         from .state_transition import interop_genesis_state
 
         self.genesis_state = interop_genesis_state(
-            self.preset, self.spec, validator_count, genesis_time=genesis_time
+            self.preset, self.spec, validator_count,
+            genesis_time=genesis_time, fork_name=fork_name,
         )
         return self
 
@@ -324,6 +328,8 @@ class ClientBuilder:
     def build(self) -> Client:
         cfg = self.config
 
+        if cfg.bls_backend == "tpu":
+            _require_tpu_platform()
         # the north-star seam: runtime backend selection
         from .crypto import backend as bls_backend
 
@@ -471,29 +477,21 @@ class ClientBuilder:
             from .crypto.device import mesh as mesh_mod
 
             if mesh_mod.env_enabled():
-                try:
-                    # mesh FIRST: the key table replicates per mesh
-                    # shard and the compile service walks the mesh
-                    # ladder — both read the seam at their own startup
-                    want = cfg.dp_devices
-                    if want is None:
-                        env_n = mesh_mod.env_devices()
-                        want = None if env_n == "all" else (env_n or 1)
-                    mesh = mesh_mod.DeviceMesh(n_devices=want)
-                    mesh_mod.set_mesh(mesh)
-                    if mesh_mod.recovery_env_enabled():
-                        # self-healing (ISSUE 13): lost chips enter
-                        # probation and a background probe re-admits
-                        # them (canary -> re-warm -> key-table re-sync)
-                        mesh.start_recovery()
-                except Exception as e:
-                    from .utils import logging as tlog
-
-                    tlog.log(
-                        "warn", "device mesh unavailable",
-                        error=repr(e)[:120],
-                    )
-                    mesh = None
+                # mesh FIRST: the key table replicates per mesh shard and
+                # the compile service walks the mesh ladder — both read
+                # the seam at their own startup. A tpu node that cannot
+                # build its mesh fails to start.
+                want = cfg.dp_devices
+                if want is None:
+                    env_n = mesh_mod.env_devices()
+                    want = None if env_n == "all" else (env_n or 1)
+                mesh = mesh_mod.DeviceMesh(n_devices=want)
+                mesh_mod.set_mesh(mesh)
+                if mesh_mod.recovery_env_enabled():
+                    # self-healing (ISSUE 13): lost chips enter
+                    # probation and a background probe re-admits
+                    # them (canary -> re-warm -> key-table re-sync)
+                    mesh.start_recovery()
         chain.device_mesh = mesh
 
         ktable = None
@@ -501,35 +499,35 @@ class ClientBuilder:
             from .crypto.device import key_table as _key_table
 
             if _key_table.env_enabled():
+                # one upload at startup mirrors the loaded cache
+                # (restart-from-store included); import_new_pubkeys
+                # admissions delta-sync through the subscription. A tpu
+                # node whose table cannot come up fails to start.
                 try:
-                    # one upload at startup mirrors the loaded cache
-                    # (restart-from-store included); import_new_pubkeys
-                    # admissions delta-sync through the subscription
                     ktable = _key_table.DeviceKeyTable(
                         chain.pubkey_cache,
                         max_aggregates=cfg.key_table_max_aggregates,
                     )
                     ktable.sync(reason="startup")
-                    _key_table.set_table(ktable)
-                    # sync_or_schedule (ISSUE 13): a failed delta
-                    # schedules a full-sync retry with backoff instead
-                    # of degrading batches to raw packs forever
-                    listener = (
-                        lambda _cache, _t=ktable:
-                        _t.sync_or_schedule(reason="delta")
-                    )
-                    chain.pubkey_cache.subscribe(listener)
-                    # stop() must be able to detach it, or admissions
-                    # would keep a dead client's table alive + syncing
-                    chain._key_table_listener = listener
-                except Exception as e:
-                    from .utils import logging as tlog
+                except BaseException:
+                    if mesh is not None:
+                        from .crypto.device import mesh as mesh_mod
 
-                    tlog.log(
-                        "warn", "device key table unavailable",
-                        error=repr(e)[:120],
-                    )
-                    ktable = None
+                        mesh.stop_recovery()
+                        mesh_mod.clear_mesh(mesh)
+                    raise
+                _key_table.set_table(ktable)
+                # sync_or_schedule (ISSUE 13): a failed delta schedules
+                # a full-sync retry with backoff instead of degrading
+                # batches to raw packs forever
+                listener = (
+                    lambda _cache, _t=ktable:
+                    _t.sync_or_schedule(reason="delta")
+                )
+                chain.pubkey_cache.subscribe(listener)
+                # stop() must be able to detach it, or admissions would
+                # keep a dead client's table alive + syncing
+                chain._key_table_listener = listener
         chain.device_key_table = ktable
 
         lookahead = None
@@ -560,10 +558,7 @@ class ClientBuilder:
                 # route cold-bucket traffic around XLA compiles; also
                 # wires the persistent executable cache into the node so
                 # a restart warm-starts from disk
-                csvc = CompileService(
-                    rungs=cfg.compile_rungs or None,
-                    cache_dir=cfg.compile_cache_dir,
-                ).start()
+                csvc = CompileService(rungs=cfg.compile_rungs or None).start()
                 set_service(csvc)  # the seam TpuBackend pads against
         chain.compile_service = csvc
 
@@ -646,6 +641,31 @@ class ClientBuilder:
         client._stop = stop
         client._lock = lock
         return client
+
+
+def _require_tpu_platform() -> None:
+    """A ``bls_backend="tpu"`` node runs on a TPU, with the C library
+    loaded (``NativeUnavailable`` otherwise): on any other platform JAX
+    would silently run the device programs on whatever it found. The
+    one exception is an explicit ``JAX_PLATFORMS=cpu`` (the test suite
+    and CPU rehearsals ask for the CPU by name)."""
+    import jax
+
+    platform = jax.devices()[0].platform
+    if platform == "tpu":
+        # admission KeyValidates every registry key: in pure Python that
+        # is ~0.14 s a key, so a chip node without the C library could
+        # not start at mainnet size
+        from .crypto import native
+
+        native.lib()
+        return
+    if platform == "cpu" and os.environ.get("JAX_PLATFORMS") == "cpu":
+        return
+    raise RuntimeError(
+        f'bls_backend="tpu" needs a TPU, but JAX found {platform!r} '
+        f"(set JAX_PLATFORMS=cpu to run the device programs on the CPU)"
+    )
 
 
 def _replay_fork_choice_gap(chain, store) -> None:

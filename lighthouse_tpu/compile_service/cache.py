@@ -2,14 +2,15 @@
 
 Two cooperating layers:
 
-* the **JAX persistent compilation cache** (``jax_compilation_cache_dir``)
-  holds the compiled executables themselves — a restarted node's AOT
-  warmup walk finds every staged program on disk and "compiles" in
-  milliseconds instead of minutes (``bench.py`` already proved this for
-  the bench harness; this module wires the same machinery into the node
-  proper). Feature-detected: older/stripped jax builds without the
-  config knob degrade to no persistence, loudly reported in
-  :func:`enable_persistent_cache`'s return value rather than raised.
+* the **JAX persistent compilation cache** holds the compiled
+  executables themselves — a restarted node's AOT warmup walk finds
+  every staged program on disk and "compiles" in milliseconds instead
+  of minutes. Its directory is placed from outside:
+  ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it itself, and no
+  code here overrides it), otherwise, on a TPU, the fixed
+  ``<repo>/.jax_cache``. A node on any other platform keeps no cache
+  unless that variable asks for one: XLA:CPU cache entries can
+  round-trip with mismatched machine features and crash on load.
 * a **manifest** (``manifest.json`` next to the cache entries) records
   WHICH rungs were baked under WHICH environment, keyed on
   backend platform | jax version | device-code hash | fp_impl |
@@ -19,12 +20,10 @@ Two cooperating layers:
   bake can never masquerade as a warm start
   (``tests/test_compile_service.py`` pins the invalidation).
 
-Known failure mode (documented in ``tests/conftest.py`` and
-``docs/COMPILE_SERVICE.md``): on some CPU host families XLA:CPU AOT
-cache entries round-trip with mismatched machine features and SIGSEGV
-on load. The node therefore only enables the cache when a directory is
-explicitly configured (``LIGHTHOUSE_TPU_COMPILE_CACHE_DIR`` or
-``ClientConfig.compile_cache_dir``) — never by default.
+The test suite turns the cache off (``jax_enable_compilation_cache``)
+for the same reason (``tests/conftest.py``); with the cache off
+:func:`enable_persistent_cache` reports it disabled and no manifest is
+kept.
 """
 
 from __future__ import annotations
@@ -34,7 +33,11 @@ import json
 import os
 import threading
 
-ENV_CACHE_DIR = "LIGHTHOUSE_TPU_COMPILE_CACHE_DIR"
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 MANIFEST_NAME = "manifest.json"
 MANIFEST_SCHEMA = "lighthouse_tpu.compile_manifest/1"
 
@@ -42,32 +45,43 @@ MANIFEST_SCHEMA = "lighthouse_tpu.compile_manifest/1"
 # to any of them changes the emitted HLO, so it must change the cache
 # key. Order is part of the hash input (kept sorted).
 _CODE_MODULES = (
-    "bls", "curve", "fp", "fp2", "htc", "pairing", "pallas_fp", "tower",
+    "bls", "curve", "fp", "fp2", "htc", "pairing", "pallas_fp",
+    "pallas_fp2", "tower",
 )
 
 
-def resolve_cache_dir(explicit: str | None = None) -> str | None:
-    """The configured cache directory: explicit arg wins, then the env
-    knob; None means persistent caching stays OFF (the safe default —
-    see the SIGSEGV note in the module docstring)."""
-    return explicit or os.environ.get(ENV_CACHE_DIR) or None
+def cache_dir() -> str:
+    """The cache directory: ``JAX_COMPILATION_CACHE_DIR`` when set,
+    otherwise ``<repo>/.jax_cache`` (used on a TPU only)."""
+    return os.environ.get(ENV_CACHE_DIR) or DEFAULT_CACHE_DIR
 
 
-def enable_persistent_cache(cache_dir: str, min_compile_time_s: float = 1.0) -> dict:
-    """Point the in-process JAX persistent compilation cache at
-    ``cache_dir``. Feature-detected, never raises: returns
-    ``{enabled, dir, reason}`` where ``reason`` explains a False."""
-    try:
-        import jax
+def enable_persistent_cache() -> dict:
+    """Turn on JAX's persistent compilation cache in :func:`cache_dir`
+    and persist every compile (``min_compile_time`` 0: JAX's default 1 s
+    floor would skip small rungs whose manifest entries still claimed a
+    warm start). Returns ``{enabled, dir, reason}``; ``enabled`` is False
+    where ``jax_enable_compilation_cache`` was turned off, and on a
+    non-TPU platform unless ``JAX_COMPILATION_CACHE_DIR`` is set."""
+    import jax
 
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", min_compile_time_s
-        )
-    except Exception as e:  # missing knob / read-only dir / old jax
-        return {"enabled": False, "dir": cache_dir, "reason": repr(e)[:200]}
-    return {"enabled": True, "dir": cache_dir, "reason": None}
+    path = cache_dir()
+    if not jax.config.jax_enable_compilation_cache:
+        return {
+            "enabled": False, "dir": path,
+            "reason": "jax_enable_compilation_cache is off",
+        }
+    if not os.environ.get(ENV_CACHE_DIR):
+        platform = jax.default_backend()
+        if platform != "tpu":
+            return {
+                "enabled": False, "dir": path,
+                "reason": f"{platform} node without {ENV_CACHE_DIR}",
+            }
+        jax.config.update("jax_compilation_cache_dir", path)
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return {"enabled": True, "dir": path, "reason": None}
 
 
 _code_hash: str | None = None

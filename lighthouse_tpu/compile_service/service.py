@@ -26,10 +26,9 @@ is that layer:
   ``shed`` (nothing warm — the scheduler serves the flush via the
   counted synchronous CPU-native fallback while the cold rung compiles
   in the background). A cold rung never stalls a flush.
-* **Persistent executable caching** — when a cache directory is
-  configured (``LIGHTHOUSE_TPU_COMPILE_CACHE_DIR`` /
-  ``ClientConfig.compile_cache_dir``) the JAX persistent compilation
-  cache plus a manifest (see :mod:`.cache`) make a restarted node's
+* **Persistent executable caching** — the JAX persistent compilation
+  cache (in ``JAX_COMPILATION_CACHE_DIR``, else ``<repo>/.jax_cache``)
+  plus a manifest (see :mod:`.cache`) make a restarted node's
   warmup walk hit disk instead of XLA: zero fresh staged compiles on
   warm start, prebaked by ``tools/warmup.py``.
 
@@ -128,7 +127,7 @@ _ENV_ENABLED = "LIGHTHOUSE_TPU_COMPILE_SERVICE"
 _ENV_RUNGS = "LIGHTHOUSE_TPU_COMPILE_RUNGS"
 # compile retry (ISSUE 13): a compile_failed rung re-queues with
 # bounded exponential backoff + jitter instead of dying — a transient
-# XLA/tunnel error must not leave a rung permanently cold — capped at
+# XLA error must not leave a rung permanently cold — capped at
 # a per-rung attempt budget so a deterministic failure cannot spin
 _ENV_RETRY_MAX = "LIGHTHOUSE_TPU_COMPILE_RETRY_MAX"
 _ENV_RETRY_BASE = "LIGHTHOUSE_TPU_COMPILE_RETRY_BASE_S"
@@ -340,15 +339,14 @@ class CompileService:
     def __init__(
         self,
         rungs: Optional[Iterable[Rung]] = None,
-        cache_dir: str | None = None,
         compile_rung_fn: Optional[Callable[[int, int, int], dict]] = None,
         fallback_verify_fn: Optional[Callable[[list], bool]] = None,
     ):
         self.plan: Tuple[Rung, ...] = tuple(
             tuple(r) for r in (rungs or _env_rungs() or DEFAULT_RUNGS)
         )
-        self.cache_dir = _cache.resolve_cache_dir(cache_dir)
-        self.cache_status: dict = {"enabled": False, "dir": None, "reason": "unconfigured"}
+        self.cache_dir: Optional[str] = None
+        self.cache_status: dict = {"enabled": False, "dir": None, "reason": "not started"}
         self.manifest: Optional[_cache.Manifest] = None
         self._compile_rung_fn = compile_rung_fn
         self._fallback_fn = fallback_verify_fn
@@ -394,19 +392,17 @@ class CompileService:
         with self._cv:
             if self._thread is not None and self._thread.is_alive():
                 return self
-            if self.cache_dir:
-                # min_compile_time 0: jax's default 1 s floor would skip
-                # persisting the small rungs' sub-second compiles while
-                # _record_ready still wrote their manifest entries — a
-                # warm-start claim with no executables behind it
-                self.cache_status = _cache.enable_persistent_cache(
-                    self.cache_dir, min_compile_time_s=0.0
-                )
-                # manifest only over a LIVE cache: entries written while
-                # the jax knob is missing/broken would claim a warm start
-                # that holds no executables (warm_warmup_s == cold)
-                if self.cache_status["enabled"]:
-                    self.manifest = _cache.Manifest(self.cache_dir)
+            if self._compile_rung_fn is None:
+                # only a service that compiles through XLA keeps a cache
+                # (an injected compile fn — stub replays — never imports
+                # jax)
+                self.cache_status = _cache.enable_persistent_cache()
+            # manifest only over a LIVE cache: entries written while the
+            # cache is off would claim a warm start that holds no
+            # executables
+            if self.cache_status["enabled"]:
+                self.cache_dir = self.cache_status["dir"]
+                self.manifest = _cache.Manifest(self.cache_dir)
             # mesh ladder (ISSUE 11): with a served dp mesh attached the
             # walk is rung x device, HEADLINE RUNGS FIRST — every chip
             # gets the big warm rung before any chip gets the next one,
@@ -833,6 +829,7 @@ class CompileService:
         source: str,
         persisted: bool = True,
         device: int = 0,
+        stage_seconds: Optional[dict] = None,
     ) -> None:
         with self._cv:  # worker thread AND organic-warmth verify threads
             self._compiled_total += 1
@@ -858,6 +855,10 @@ class CompileService:
             source=source,
             persisted=persisted,
             device=device,
+            **{
+                f"{stage}_s": (stage_seconds or {}).get(stage)
+                for stage in ("stage1", "stage2", "stage3")
+            },
         )
 
     # -- background worker ------------------------------------------------
@@ -1096,6 +1097,10 @@ class CompileService:
             self._record_ready(
                 rung, impl, seconds=seconds, source="aot",
                 persisted=persisted, device=dev,
+                stage_seconds={
+                    stage: round(float(rec.get("seconds", 0.0)), 3)
+                    for stage, rec in (stages or {}).items()
+                },
             )
 
     # -- introspection ----------------------------------------------------

@@ -43,6 +43,27 @@ class PublicKey:
     def deserialize(cls, data: bytes) -> "PublicKey":
         if len(data) != PUBLIC_KEY_BYTES:
             raise BlsError(f"invalid pubkey length {len(data)}")
+        data = bytes(data)
+        # Fast path through the C library (same KeyValidate rules); the
+        # pure-Python path below stays as the oracle where it cannot load.
+        from .native import NativeUnavailable, native_pubkey_xy
+
+        try:
+            xy = native_pubkey_xy(data)
+        except NativeUnavailable:
+            return cls.deserialize_oracle(data)
+        if xy is None:
+            raise BlsError("invalid public key encoding")
+        from .cpu.fields import Fq
+
+        return cls(G1Point(Fq(xy[0]), Fq(xy[1])), data)
+
+    @classmethod
+    def deserialize_oracle(cls, data: bytes) -> "PublicKey":
+        """Pure-Python KeyValidate: the reference the native path is
+        differential-tested against."""
+        if len(data) != PUBLIC_KEY_BYTES:
+            raise BlsError(f"invalid pubkey length {len(data)}")
         try:
             point = G1Point.decompress(data)
         except ValueError as e:

@@ -707,10 +707,15 @@ def _staged_verify(
         sg, fg = gather_record
         gather_fields = {"gathered": True, "gather_s": round(sg, 6)}
         recompiled = recompiled or bool(fg)
+    # the caller kind the scheduler scoped this dispatch to, and the
+    # device the verdict was computed on (platform:id of the stage output)
+    kind, _path = transfer_ledger.current_context()
+    device = next(iter(out.devices()))
     flight_recorder.record(
         "bls_stage_verify",
         stage1_s=round(s1, 6), stage2_s=round(s2, 6), stage3_s=round(s3, 6),
-        recompiled=recompiled, verdict=verdict, **gather_fields, **geometry,
+        recompiled=recompiled, verdict=verdict, kind=kind,
+        device=f"{device.platform}:{device.id}", **gather_fields, **geometry,
     )
     # the data-movement row this thread's pack staged (transfer_ledger):
     # the verdict read is the only device→host transfer of a staged

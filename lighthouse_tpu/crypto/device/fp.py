@@ -40,7 +40,7 @@ Multiplication exists in selectable implementations (``FP_IMPL``):
   machine-checked to recombine without overflow.
 * ``pallas_int8`` — the same int8 decomposition as a hand-placed Pallas
   kernel (``pallas_fp.py``), for when XLA keeps the int8 dots on the
-  VPU; interpreted off-TPU, so it stays differential-testable.
+  VPU; interpreted on the CPU, so it stays differential-testable.
 
 Select with ``LIGHTHOUSE_TPU_FP_IMPL`` (env, like the BLS backend flag in
 ``crypto/backend.py``) or :func:`set_impl` / the :func:`impl` context
@@ -206,12 +206,16 @@ def _fold_round(cols, bounds):
     k = n - NL
     assert k > 0
     lo, hi = cols[..., :NL], cols[..., NL:]
-    table = (
-        jnp.asarray(FOLD[:k]) if _FOLD_OVERRIDE is None
-        else _FOLD_OVERRIDE[:k]
-    )
-    out = lo + jnp.einsum("...h,hl->...l", hi, table,
-                          preferred_element_type=jnp.int32)
+    if _FOLD_OVERRIDE is None:
+        out = lo + jnp.einsum("...h,hl->...l", hi, jnp.asarray(FOLD[:k]),
+                              preferred_element_type=jnp.int32)
+    else:
+        # inside a Pallas kernel: Mosaic has no int32 matmul, so the
+        # fold is k broadcast multiply-adds on the VPU (same terms, same
+        # non-negative partial sums, so the same bounds hold)
+        out = lo
+        for h in range(k):
+            out = out + hi[..., h:h + 1] * _FOLD_OVERRIDE[h:h + 1, :]
     ob = [bounds[i] + sum(bounds[NL + h] * int(FOLD[h, i]) for h in range(k))
           for i in range(NL)]
     assert all(b < 2 ** 31 for b in ob), f"fold overflow risk: {ob}"
@@ -357,7 +361,7 @@ def _mul_matmul_int8(x, y):
 def _mul_pallas_int8(x, y):
     """The int8 decomposition as a hand-placed Pallas kernel (see
     ``pallas_fp.py``) for when the dot_general lowering refuses to leave
-    the VPU; interpreted off-TPU so it stays differential-testable."""
+    the VPU; interpreted on the CPU so it stays differential-testable."""
     from . import pallas_fp
 
     return reduce_cols(pallas_fp.mul_cols_int8(x, y), MUL_COL_BOUNDS)

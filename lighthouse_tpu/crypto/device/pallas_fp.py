@@ -5,8 +5,8 @@ This is the hand-placed fallback behind ``FP_IMPL=pallas_int8`` (see
 keeps the int8 contractions on the VPU, this kernel states the placement
 explicitly — int8 operand tiles in VMEM, four s8 x s8 -> s32 dot passes
 per batch tile, shift-recombined in-register before the columns leave the
-kernel. Off-TPU it runs in interpreter mode so the whole differential test
-matrix (vs the Python oracle and the int32 path) still covers it.
+kernel. On the CPU it runs in interpreter mode so the whole differential
+test matrix (vs the Python oracle and the int32 path) still covers it.
 
 The kernel computes RAW product columns only; the caller reduces them mod
 p through ``fp.reduce_cols`` with the shared full-band bound profile
@@ -28,16 +28,18 @@ TILE = 8
 
 
 def _mul_tile_kernel(split_shift: int, xs_ref, bs_ref, out_ref):
-    """One batch tile: xs [2, T, NL] int8, bs [2, T, NL, NCOLS] int8 ->
-    out [T, NCOLS] int32 raw product columns."""
+    """One batch tile: xs [2, T, 1, NL] int8, bs [2, T, NL, NCOLS] int8
+    -> out [T, NCOLS] int32 raw product columns."""
     from jax import lax
 
     def dot(a, b):
-        # [T, NL] x [T, NL, NCOLS] -> [T, NCOLS], batched over T, int32 acc
+        # [T, 1, NL] x [T, NL, NCOLS] -> [T, NCOLS], batched over T, int32
+        # acc. The unit LHS row keeps a non-contracting dimension, which
+        # Mosaic's batched matmul requires.
         return lax.dot_general(
-            a, b, (((1,), (1,)), ((0,), (0,))),
+            a, b, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.int32,
-        )
+        )[:, 0, :]
 
     xh, xl = xs_ref[0], xs_ref[1]
     bh, bl = bs_ref[0], bs_ref[1]
@@ -52,9 +54,18 @@ def _mul_tile_kernel(split_shift: int, xs_ref, bs_ref, out_ref):
 
 @functools.cache
 def _interpret() -> bool:
-    # Interpreter mode everywhere but a real TPU: the kernel is then a
-    # reference semantics check, not a performance path.
-    return jax.default_backend() != "tpu"
+    """Compiled on a TPU, interpreted on the CPU (where the kernel is a
+    reference semantics check, not a performance path). Any other
+    platform is refused rather than silently interpreted."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for tpu and interpret on cpu; "
+        f"platform {platform!r} is neither"
+    )
 
 
 def mul_cols_int8(x, y):
@@ -79,14 +90,14 @@ def mul_cols_int8(x, y):
         bf = jnp.pad(bf, ((0, pad), (0, 0), (0, 0)))
     npad = n + pad
 
-    xs = fp.split_int8(xf)                  # [2, npad, NL]
+    xs = fp.split_int8(xf)[:, :, None, :]   # [2, npad, 1, NL]
     bs = fp.split_int8(bf)                  # [2, npad, NL, NCOLS]
 
     cols = pl.pallas_call(
         functools.partial(_mul_tile_kernel, fp.SPLIT_SHIFT),
         grid=(npad // TILE,),
         in_specs=[
-            pl.BlockSpec((2, TILE, fp.NL), lambda i: (0, i, 0)),
+            pl.BlockSpec((2, TILE, 1, fp.NL), lambda i: (0, i, 0, 0)),
             pl.BlockSpec((2, TILE, fp.NL, fp.NCOLS), lambda i: (0, i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((TILE, fp.NCOLS), lambda i: (i, 0)),

@@ -9,7 +9,7 @@ combine all run inside one Pallas tile, so the product never round-trips
 raw columns through HBM between the contraction and the combine.
 
 Selected via ``LIGHTHOUSE_TPU_FP2_IMPL=fused_pallas`` (see ``fp2.py``);
-off-TPU the kernels run in interpreter mode, so the full differential
+on the CPU the kernels run in interpreter mode, so the full differential
 matrix (vs the Python Fq2 oracle and the composed path) covers them on
 any host.
 
@@ -37,20 +37,23 @@ import jax.numpy as jnp
 from .pallas_fp import TILE, _interpret
 
 
-def _raw_cols(split_shift, xs_ref, bs_ref):
-    """Shared contraction: the four int8 dot passes + shift recombination
-    -> exact int32 product columns [T, R, NCOLS]."""
+def _raw_cols(split_shift, xs_ref, bs_ref, r):
+    """Shared contraction for operand row ``r``: the four int8 dot
+    passes + shift recombination -> exact int32 product columns
+    [T, NCOLS]."""
     from jax import lax
 
     def dot(a, b):
-        # [T, R, NL] x [T, R, NL, NCOLS] -> [T, R, NCOLS]; int32 acc
+        # [T, 1, NL] x [T, NL, NCOLS] -> [T, NCOLS]; int32 acc. The unit
+        # LHS row keeps a non-contracting dimension, which Mosaic's
+        # batched matmul requires.
         return lax.dot_general(
-            a, b, (((2,), (2,)), ((0, 1), (0, 1))),
+            a, b, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.int32,
-        )
+        )[:, 0, :]
 
-    xh, xl = xs_ref[0], xs_ref[1]
-    bh, bl = bs_ref[0], bs_ref[1]
+    xh, xl = xs_ref[0, r], xs_ref[1, r]
+    bh, bl = bs_ref[0, r], bs_ref[1, r]
     return (
         (dot(xh, bh) << (2 * split_shift))
         + ((dot(xh, bl) + dot(xl, bh)) << split_shift)
@@ -69,11 +72,13 @@ def _karatsuba_tile_kernel(split_shift, xs_ref, bs_ref, fold_ref, sat_ref,
     """
     from . import fp
 
-    raw = _raw_cols(split_shift, xs_ref, bs_ref)
     sat = sat_ref[...]
     with fp.fold_table(fold_ref[...]):
-        t = fp.reduce_cols(raw, fp.MUL_COL_BOUNDS)   # [T, 3, NL] relaxed
-        t0, t1, m = t[:, 0], t[:, 1], t[:, 2]
+        t0, t1, m = (
+            fp.reduce_cols(_raw_cols(split_shift, xs_ref, bs_ref, r),
+                           fp.MUL_COL_BOUNDS)       # [T, NL] relaxed
+            for r in range(3)
+        )
         # c0 = t0 - t1, c1 = m - t0 - t1, each in ONE reduction via SAT
         c0 = fp.reduce_cols(
             t0 + (sat - t1), [fp.LIMB_MAX + int(v) for v in fp.SAT]
@@ -82,7 +87,8 @@ def _karatsuba_tile_kernel(split_shift, xs_ref, bs_ref, fold_ref, sat_ref,
             m + (2 * sat - t0 - t1),
             [fp.LIMB_MAX + 2 * int(v) for v in fp.SAT],
         )
-    out_ref[:] = jnp.stack([c0, c1], axis=1)
+    out_ref[0] = c0
+    out_ref[1] = c1
 
 
 def _sq_tile_kernel(split_shift, xs_ref, bs_ref, fold_ref, sat_ref, out_ref):
@@ -91,12 +97,15 @@ def _sq_tile_kernel(split_shift, xs_ref, bs_ref, fold_ref, sat_ref, out_ref):
     bs [2, T, 2, NL, NCOLS] int8 -> out [T, 2, NL] int32."""
     from . import fp
 
-    raw = _raw_cols(split_shift, xs_ref, bs_ref)
     with fp.fold_table(fold_ref[...]):
-        t = fp.reduce_cols(raw, fp.MUL_COL_BOUNDS)   # [T, 2, NL]
-        t0, t1 = t[:, 0], t[:, 1]
+        t0, t1 = (
+            fp.reduce_cols(_raw_cols(split_shift, xs_ref, bs_ref, r),
+                           fp.MUL_COL_BOUNDS)       # [T, NL]
+            for r in range(2)
+        )
         c1 = fp.reduce_cols(t1 + t1, [2 * fp.LIMB_MAX] * fp.NL)
-    out_ref[:] = jnp.stack([t0, c1], axis=1)
+    out_ref[0] = t0
+    out_ref[1] = c1
 
 
 def _run_rows(kernel, xrows, yrows):
@@ -111,35 +120,40 @@ def _run_rows(kernel, xrows, yrows):
     n = 1
     for d in lead:
         n *= d
-    xf = xrows.reshape(n, nrows, fp.NL)
-    bf = fp.band_matrix(yrows.reshape(n, nrows, fp.NL))
+    # row-major operand layout [R, n, ...]: each Karatsuba row is its
+    # own [T, 1, NL] x [T, NL, NCOLS] batched matmul inside the kernel
+    xf = jnp.moveaxis(xrows.reshape(n, nrows, fp.NL), 1, 0)
+    bf = fp.band_matrix(jnp.moveaxis(yrows.reshape(n, nrows, fp.NL), 1, 0))
 
     pad = (-n) % TILE
     if pad:
-        xf = jnp.pad(xf, ((0, pad), (0, 0), (0, 0)))
-        bf = jnp.pad(bf, ((0, pad), (0, 0), (0, 0), (0, 0)))
+        xf = jnp.pad(xf, ((0, 0), (0, pad), (0, 0)))
+        bf = jnp.pad(bf, ((0, 0), (0, pad), (0, 0), (0, 0)))
     npad = n + pad
 
-    xs = fp.split_int8(xf)                  # [2, npad, R, NL]
-    bs = fp.split_int8(bf)                  # [2, npad, R, NL, NCOLS]
+    xs = fp.split_int8(xf)[..., None, :]    # [2, R, npad, 1, NL]
+    bs = fp.split_int8(bf)                  # [2, R, npad, NL, NCOLS]
 
     nfold = fp.FOLD.shape[0]
     out = pl.pallas_call(
         functools.partial(kernel, fp.SPLIT_SHIFT),
         grid=(npad // TILE,),
         in_specs=[
-            pl.BlockSpec((2, TILE, nrows, fp.NL), lambda i: (0, i, 0, 0)),
             pl.BlockSpec(
-                (2, TILE, nrows, fp.NL, fp.NCOLS), lambda i: (0, i, 0, 0, 0)
+                (2, nrows, TILE, 1, fp.NL), lambda i: (0, 0, i, 0, 0)
+            ),
+            pl.BlockSpec(
+                (2, nrows, TILE, fp.NL, fp.NCOLS),
+                lambda i: (0, 0, i, 0, 0),
             ),
             pl.BlockSpec((nfold, fp.NL), lambda i: (0, 0)),
             pl.BlockSpec((fp.NL,), lambda i: (0,)),
         ],
-        out_specs=pl.BlockSpec((TILE, 2, fp.NL), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((npad, 2, fp.NL), jnp.int32),
+        out_specs=pl.BlockSpec((2, TILE, fp.NL), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((2, npad, fp.NL), jnp.int32),
         interpret=_interpret(),
     )(xs, bs, jnp.asarray(fp.FOLD), jnp.asarray(fp.SAT))
-    return out[:n].reshape(*lead, 2, fp.NL)
+    return jnp.moveaxis(out[:, :n], 0, 1).reshape(*lead, 2, fp.NL)
 
 
 def mul2(x, y):

@@ -103,6 +103,19 @@ def native_sk_to_pk_xy(sk_int: int) -> tuple[int, int]:
     return int.from_bytes(raw[:48], "big"), int.from_bytes(raw[48:], "big")
 
 
+def native_pubkey_xy(data: bytes) -> tuple[int, int] | None:
+    """KeyValidate a compressed 48-byte G1 public key through the C
+    library (decompress, on-curve, subgroup, infinity rejected): affine
+    (x, y) ints, or None for an invalid encoding. ~100x faster than the
+    pure-Python oracle, which makes admitting a mainnet-size registry
+    take seconds instead of hours."""
+    out = (ctypes.c_uint8 * 96)()
+    if lib().bls_g1_pubkey_check(bytes(data), out) != 1:
+        return None
+    raw = bytes(out)
+    return int.from_bytes(raw[:48], "big"), int.from_bytes(raw[48:], "big")
+
+
 def native_sign(sk_int: int, signing_root: bytes) -> bytes:
     """[sk] H(root) as compressed bytes via the C library — a fast signer
     for benchmark/test workload generation (~ms instead of the oracle's

@@ -303,6 +303,10 @@ class DutyLookahead:
             counts = {"device": 0, "host": 0, "virtual": 0, "failed": 0}
             inserts: Dict[str, int] = {}
             for idxs in committees:
+                if self._stop.is_set():
+                    # stop() mid-warm: leave the epoch unwarmed rather
+                    # than outlive the client in a daemon thread
+                    return None
                 path = self._warm_one(idxs, epoch, inserts)
                 counts[path] += 1
         except Exception as e:

@@ -219,6 +219,12 @@ class ApiError(Exception):
         self.message = message
 
 
+class _HttpServer(ThreadingHTTPServer):
+    # listen backlog: validator clients post their duties at the same
+    # instant of the slot; socketserver's default of 5 resets the rest
+    request_queue_size = 256
+
+
 class BeaconApiServer:
     """``chain`` is the BeaconChain; ``op_pool`` its pool. Runs on a
     daemon thread; ``port=0`` picks a free port (tests)."""
@@ -252,7 +258,7 @@ class BeaconApiServer:
             def do_POST(self):
                 outer._dispatch(self, "POST")
 
-        self.httpd = ThreadingHTTPServer((host, port), Handler)
+        self.httpd = _HttpServer((host, port), Handler)
         self.port = self.httpd.server_address[1]
         self._thread = threading.Thread(
             target=self.httpd.serve_forever, daemon=True

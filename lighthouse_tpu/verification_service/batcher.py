@@ -112,7 +112,7 @@ decision, and its own bisection scope; submissions stay atomic, so
 per-submission futures and verdict identity are untouched.
 
 Dispatch watchdog (ISSUE 13): a sharded sub-batch dispatch can HANG —
-a wedged device tunnel, a runaway injected stall — and before the
+a wedged device, a runaway injected stall — and before the
 watchdog that hang wedged the flush thread (or its dp worker) forever.
 With a deadline configured (``watchdog_s`` / env
 ``LIGHTHOUSE_TPU_SCHED_WATCHDOG_S``), each sharded dispatch runs on a

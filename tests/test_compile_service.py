@@ -595,6 +595,72 @@ def test_decide_flush_padded_requires_global_seam():
         svc.stop()
 
 
+def test_flush_padded_onto_larger_warm_rung_is_a_cold_route():
+    """A flush whose exact bucket is cold but covered by a larger warm
+    rung dispatches on the device at that rung, counts one ``padded``
+    cold route and queues its exact bucket for compilation — whether the
+    planner kept it whole or placed it on the warm rung itself."""
+    device_calls = []
+
+    def device_verify(sets):
+        device_calls.append(list(sets))
+        return all(sets)
+
+    gate = threading.Event()  # never released: the exact bucket stays cold
+    run, _ = _fake_compile(gate=gate)
+    svc = CompileService(
+        rungs=((1, 1, 1),),
+        compile_rung_fn=run,
+        fallback_verify_fn=lambda sets: pytest.fail("fell back"),
+    ).start()
+    svc.registry.mark_ready((8, 1, 8), IMPL)
+    set_service(svc)
+    sched = VerificationScheduler(
+        verify_fn=device_verify, deadline_ms=20.0, compile_service=svc,
+    ).start()
+    padded = metrics.get("compile_service_cold_routes_total").with_labels(
+        "padded"
+    )
+    before = padded.value
+    try:
+        # 3 one-key sets, each its own message: exact bucket (4, 1, 4)
+        fut = sched.submit([True, True, True], "aggregate")
+        assert fut.result(timeout=10) is True
+        assert device_calls
+        assert padded.value == before + 1
+        row = fr.events(kinds=("cold_route",))[-1]["fields"]
+        assert row["action"] == "padded"
+        assert row["caller"].startswith("flush:")
+        assert (row["exact_b"], row["exact_k"], row["exact_m"]) == (4, 1, 4)
+        assert (row["warm_b"], row["warm_k"], row["warm_m"]) == (8, 1, 8)
+        assert ((4, 1, 4), 0) in svc._queued
+    finally:
+        sched.stop()
+        clear_service(svc)
+        gate.set()
+        svc.stop()
+
+
+def test_cache_default_dir_is_tpu_only(monkeypatch):
+    """Without JAX_COMPILATION_CACHE_DIR a non-TPU node keeps no cache
+    (XLA:CPU entries can crash on load) and sets no cache directory;
+    with it set, the variable's directory is used as it stands."""
+    import jax
+
+    monkeypatch.delenv(cs_cache.ENV_CACHE_DIR, raising=False)
+    was_on = jax.config.jax_enable_compilation_cache
+    dir_before = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_enable_compilation_cache", True)
+    try:
+        st = cs_cache.enable_persistent_cache()
+        assert st["enabled"] is False
+        assert st["dir"] == cs_cache.DEFAULT_CACHE_DIR
+        assert "cpu" in st["reason"]
+        assert jax.config.jax_compilation_cache_dir == dir_before
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+
+
 # ---------------------------------------------------------------------------
 # Manifest honesty (the cache may hold fewer executables than the
 # compile walk produced; the manifest must never claim more)

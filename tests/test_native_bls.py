@@ -148,6 +148,78 @@ def test_differential_vs_python_oracle():
         assert cpu.verify_signature_sets([case]) is expected
 
 
+def _g1_encoding(x: int, sign: bool = False) -> bytes:
+    raw = bytearray(x.to_bytes(48, "big"))
+    raw[0] |= 0x80 | (0x20 if sign else 0)
+    return bytes(raw)
+
+
+def _g1_x(on_curve: bool) -> int:
+    """Smallest x whose curve equation y^2 = x^3 + 4 has (or lacks) a
+    root. An on-curve x found this way is outside the r-order subgroup
+    (the cofactor is ~2^126), which the test asserts."""
+    from lighthouse_tpu.crypto.cpu.curve import G1Point
+
+    for x in range(1, 200):
+        try:
+            G1Point.decompress(_g1_encoding(x))
+            found = True
+        except ValueError:
+            found = False
+        if found == on_curve:
+            return x
+    raise AssertionError("no such x below 200")
+
+
+def _admission_case(name: str) -> bytes:
+    if name.startswith("valid"):
+        return PK[int(name[-1])].serialize()
+    if name == "off_curve":
+        return _g1_encoding(_g1_x(on_curve=False))
+    if name == "non_subgroup":
+        from lighthouse_tpu.crypto.cpu.curve import G1Point
+
+        raw = _g1_encoding(_g1_x(on_curve=True))
+        assert not G1Point.decompress(raw).in_subgroup()
+        return raw
+    if name == "infinity":
+        return bls.INFINITY_PUBLIC_KEY
+    if name == "malformed_infinity":
+        return bytes([0xC0] + [0] * 46 + [1])
+    if name == "x_out_of_range":
+        return _g1_encoding(P)
+    if name == "uncompressed_flag":
+        return bytes([PK[0].serialize()[0] & 0x7F]) + PK[0].serialize()[1:]
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize(
+    "name",
+    [
+        "valid0", "valid5", "off_curve", "non_subgroup", "infinity",
+        "malformed_infinity", "x_out_of_range", "uncompressed_flag",
+    ],
+)
+def test_pubkey_admission_matches_oracle(name):
+    """Native KeyValidate (the admission path of PublicKey.deserialize)
+    accepts and rejects exactly what the pure-Python oracle does, and
+    yields the same point."""
+    raw = _admission_case(name)
+    try:
+        want = bls.PublicKey.deserialize_oracle(raw)
+    except bls.BlsError:
+        want = None
+    try:
+        got = bls.PublicKey.deserialize(raw)
+    except bls.BlsError:
+        got = None
+    assert (got is None) == (want is None)
+    assert (want is not None) == name.startswith("valid")
+    if want is not None:
+        assert (got.point.x.n, got.point.y.n) == (want.point.x.n, want.point.y.n)
+        assert got.serialize() == want.serialize() == raw
+
+
 def test_backend_registry_selection():
     backend.set_backend("cpu-native")
     try:
@@ -166,3 +238,34 @@ def test_backend_registry_selection():
         assert backend.active().verify_signature_sets([(sig, pks, m)]) is True
     finally:
         backend.set_backend("cpu")
+
+
+def test_native_build_is_keyed_and_replaces_stale(tmp_path, monkeypatch):
+    """A library is named by the hash of its source, local headers,
+    flags and compiler; editing an included header builds a new one and
+    deletes the old, while a build in progress is left alone."""
+    import sysconfig
+
+    from lighthouse_tpu import _native
+
+    monkeypatch.setattr(_native, "_DIR", tmp_path)
+    suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
+    (tmp_path / "probe.c").write_text(
+        '#include "probe.h"\nint probe(void) { return PROBE; }\n'
+    )
+    (tmp_path / "probe.h").write_text("#define PROBE 1\n")
+    legacy = tmp_path / f"libprobe{suffix}"
+    legacy.write_bytes(b"")
+    first = _native._build_and_load_uncached("probe")
+    assert first is not None and first.probe() == 1
+    built = sorted(p.name for p in tmp_path.glob("libprobe*"))
+    assert len(built) == 1 and built[0] != legacy.name
+    in_progress = tmp_path / (built[0][: -len(suffix)] + ".tmp999999")
+    in_progress.write_bytes(b"")
+
+    (tmp_path / "probe.h").write_text("#define PROBE 2\n")
+    second = _native._build_and_load_uncached("probe")
+    assert second is not None and second.probe() == 2
+    now = sorted(p.name for p in tmp_path.glob(f"libprobe*{suffix}"))
+    assert len(now) == 1 and now != built
+    assert in_progress.exists()

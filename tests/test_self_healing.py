@@ -235,6 +235,48 @@ def test_client_stop_during_active_probation_probe():
         bls_backend.set_backend("cpu")
 
 
+def _tpu_builder():
+    from lighthouse_tpu.client import ClientBuilder, ClientConfig
+    from lighthouse_tpu.types.chain_spec import minimal_spec
+
+    return ClientBuilder(
+        ClientConfig(
+            preset_base="minimal", http_enabled=False, bls_backend="tpu",
+            verification_scheduler=False, compile_service=False,
+        ),
+        minimal_spec(),
+    ).with_interop_genesis(8)
+
+
+def test_tpu_node_refuses_the_cpu_unless_asked_by_name(monkeypatch):
+    """A tpu node on a machine whose JAX found no TPU fails to build;
+    only an explicit JAX_PLATFORMS=cpu (the test suite's own) lets the
+    device programs run on the CPU."""
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        _tpu_builder().build()
+    assert mesh_mod.get_active_mesh() is None
+
+
+def test_tpu_node_without_its_key_table_fails_to_start(monkeypatch):
+    """A key-table start-up failure stops the build (it used to log a
+    warning and serve without the table) and leaves no mesh behind."""
+    from lighthouse_tpu.crypto import backend as bls_backend
+    from lighthouse_tpu.crypto.device import key_table
+
+    def no_memory(self, reason="delta"):
+        raise RuntimeError("no device memory for the key table")
+
+    monkeypatch.setattr(key_table.DeviceKeyTable, "sync", no_memory)
+    try:
+        with pytest.raises(RuntimeError, match="no device memory"):
+            _tpu_builder().build()
+        assert mesh_mod.get_active_mesh() is None
+        assert key_table.get_active_table() is None
+    finally:
+        bls_backend.set_backend("cpu")
+
+
 def test_concurrent_loss_and_recovery_under_8_thread_traffic(mesh2):
     """The concurrency race the issue names: 8 submitter threads drive
     continuous traffic while shard 1 dies and recovers mid-stream —

@@ -165,11 +165,13 @@ def test_zgate6_cold_rung_never_stalls_flush_then_runs_warm():
 _CHILD = r"""
 import json, os, sys, time
 os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["JAX_COMPILATION_CACHE_DIR"] = {cache!r}
 sys.path.insert(0, {repo!r})
 import jax
 jax.config.update("jax_platforms", "cpu")
 from lighthouse_tpu.compile_service import cache as cs_cache
-status = cs_cache.enable_persistent_cache({cache!r}, min_compile_time_s=0.0)
+status = cs_cache.enable_persistent_cache()
+assert status["dir"] == {cache!r}, status
 if not status["enabled"]:
     print(json.dumps({{"unsupported": status["reason"]}}))
     raise SystemExit(0)
@@ -208,8 +210,8 @@ def test_zgate6_persistent_cache_warm_restart_subprocess(tmp_path):
     persistent cache + manifest enabled; the second child (a "restarted
     node") must find the manifest entry prebaked — the node-level
     warm-start signal — and load the executable from disk instead of
-    compiling fresh. Loud skips where the JAX build has no cache knob or
-    where this host family's known XLA:CPU cache-load SIGSEGV fires."""
+    compiling fresh. Loud skip where this host family's known XLA:CPU
+    cache-load SIGSEGV fires."""
     cache_dir = str(tmp_path / "cache")
 
     def run_child():
@@ -233,8 +235,7 @@ def test_zgate6_persistent_cache_warm_restart_subprocess(tmp_path):
     if doc1["n_cache_files"] == 0:
         pytest.skip(
             "persistent cache wrote no entries on this jax build — "
-            "warm-restart unverifiable here (bench.py startup leg still "
-            "records it on hosts where the cache works)"
+            "warm-restart unverifiable here"
         )
 
     r2 = run_child()

@@ -6,15 +6,14 @@ with zero fresh XLA staged compiles.
     # list the walk without importing jax or compiling anything
     python tools/warmup.py --dry-run
 
-    # bake the default ladder under the active engine
-    LIGHTHOUSE_TPU_COMPILE_CACHE_DIR=/var/cache/lighthouse \\
-        python tools/warmup.py
+    # bake the default ladder under the active engine into the cache in
+    # force (JAX_COMPILATION_CACHE_DIR, else <repo>/.jax_cache)
+    python tools/warmup.py
 
-    # bake specific rungs into an explicit dir, one JSON line at the end
-    python tools/warmup.py --cache-dir /tmp/cache --rungs 4:1:1,64:16:8 --json
+    # bake specific rungs, one JSON line at the end
+    python tools/warmup.py --rungs 4:1:1,64:16:8 --json
 
-The platform is whatever JAX resolves (set ``JAX_PLATFORMS=cpu`` to bake
-an XLA:CPU cache, e.g. the bench fallback). Each rung compiles the three
+The platform is whatever JAX resolves. Each rung compiles the three
 staged programs through the same ``lowering.warm_staged`` path the
 in-node service uses, so the executables, the manifest entries and the
 recompile accounting all match what the node will look for.
@@ -48,13 +47,6 @@ def _parse_rungs(raw: str):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument(
-        "--cache-dir",
-        default=None,
-        help="persistent cache directory (default: "
-        "LIGHTHOUSE_TPU_COMPILE_CACHE_DIR; omit both to warm jit caches "
-        "for this process only, persisting nothing)",
-    )
     ap.add_argument(
         "--rungs",
         default=None,
@@ -92,7 +84,7 @@ def main(argv=None) -> int:
         if args.rungs
         else (csvc_mod._env_rungs() or csvc_mod.DEFAULT_RUNGS)
     )
-    cache_dir = cache_mod.resolve_cache_dir(args.cache_dir)
+    cache_dir = cache_mod.cache_dir()
 
     if args.devices <= 0:
         raise SystemExit("--devices must be positive")
@@ -142,28 +134,21 @@ def main(argv=None) -> int:
         )
         for n in csvc_mod.MSM_RUNGS:
             print(f"  msm N={n}")
-        print(f"cache_dir: {cache_dir or '(none — nothing would persist)'}")
+        print(f"cache_dir: {cache_dir}")
         return 0
 
-    cache_status = {"enabled": False, "dir": cache_dir, "reason": "unconfigured"}
     manifest = None
-    if cache_dir:
-        # min_compile_time 0 matches the in-node service: jax's default
-        # 1 s floor would skip persisting small rungs while their
-        # manifest entries still claimed a warm start
-        cache_status = cache_mod.enable_persistent_cache(
-            cache_dir, min_compile_time_s=0.0
+    cache_status = cache_mod.enable_persistent_cache()
+    if cache_status["enabled"]:
+        manifest = cache_mod.Manifest(cache_dir)
+    else:
+        # no manifest over a dead cache: a prebaked claim with no
+        # executables behind it would falsify warm-start reporting
+        print(
+            f"persistent cache off ({cache_status['reason']}); "
+            f"warming this process only",
+            file=sys.stderr,
         )
-        if cache_status["enabled"]:
-            manifest = cache_mod.Manifest(cache_dir)
-        else:
-            # no manifest over a dead cache: a prebaked claim with no
-            # executables behind it would falsify warm-start reporting
-            print(
-                f"persistent cache UNAVAILABLE ({cache_status['reason']}); "
-                f"warming this process only",
-                file=sys.stderr,
-            )
 
     from lighthouse_tpu.compile_service import lowering
     from lighthouse_tpu.crypto.device import fp
