@@ -53,6 +53,7 @@ from ..cpu.pairing import PSI_CX, PSI_CY
 from ..cpu.hash_to_curve import hash_to_g2
 from . import curve, fp, fp2, msm as msm_mod, pairing, tower
 from .pairing import X_ABS
+from .scopes import scoped
 
 # psi constants (public, derived from xi; see cpu/pairing.py:22-27).
 _PSI_CX = (PSI_CX.c0.n, PSI_CX.c1.n)
@@ -180,12 +181,14 @@ def _fp_gt(a_digits, b_digits):
     return (msd > 0) & pick
 
 
+@scoped("decompress")
 def _decompress_pre(sig_x):
     """g(x) = x^3 + 4(1+u) — the radicand awaiting a sqrt ladder."""
     b2 = jnp.broadcast_to(fp2.const(4, 4), sig_x.shape).astype(jnp.int32)
     return fp2.add(fp2.mul(fp2.sq(sig_x), sig_x), b2)
 
 
+@scoped("decompress")
 def _decompress_post(sign_larger, y, ok):
     """Sign selection by the compressed flag's lexicographic-larger rule;
     ``y, ok`` are the sqrt outputs for ``_decompress_pre``'s radicand."""
@@ -298,6 +301,7 @@ verify_batch_raw = jax.jit(verify_batch_raw_fn)
 # one stage (e.g. more unique messages M) recompile only that stage.
 # ---------------------------------------------------------------------------
 
+@scoped("bls.stage1")
 def _stage1_fn(sig_x, sig_larger, msg_u):
     """Decompression + hash-to-curve (all square roots in one ladder)."""
     from . import htc
@@ -317,35 +321,44 @@ def _stage1_fn(sig_x, sig_larger, msg_u):
         roots[B:].reshape(M, 2, 2, 2, fp.NL),
         root_ok[B:].reshape(M, 2, 2),
     )
-    mx, my, minf = curve.to_affine(fp2, msg_pts)
+    with jax.named_scope("to_affine"):
+        mx, my, minf = curve.to_affine(fp2, msg_pts)
     return sig_xy, mx, my, minf, sig_ok
 
 
+@scoped("bls.stage2")
 def _stage2_fn(pk_xy, pk_mask, sig_xy, rand_bits, set_mask):
     """Aggregation + subgroup checks + random scaling -> affine pairing
     inputs for the G1 side and the G2 signature accumulator."""
     B = pk_xy.shape[0]
-    pk_pts = curve.from_affine(fp, pk_xy[..., 0, :], pk_xy[..., 1, :], ~pk_mask)
-    agg_pk = curve.sum_points(fp, pk_pts, axis=1)
+    with jax.named_scope("key_sum"):
+        pk_pts = curve.from_affine(
+            fp, pk_xy[..., 0, :], pk_xy[..., 1, :], ~pk_mask
+        )
+        agg_pk = curve.sum_points(fp, pk_pts, axis=1)
 
     sig_pts = curve.from_affine(fp2, sig_xy[..., 0, :, :], sig_xy[..., 1, :, :])
     bits = _bits64(rand_bits) if rand_bits.shape[-1] == 2 else rand_bits
     xbits = jnp.broadcast_to(jnp.asarray(_XBITS64), (B, 64))
-    both = curve.scalar_mul_bits(
-        fp2,
-        tuple(jnp.concatenate([c, c], axis=0) for c in sig_pts),
-        jnp.concatenate([xbits, bits], axis=0),
-    )
+    # one ladder serves the subgroup check's [|x|]Q and the random [r]Q
+    with jax.named_scope("g2_ladder"):
+        both = curve.scalar_mul_bits(
+            fp2,
+            tuple(jnp.concatenate([c, c], axis=0) for c in sig_pts),
+            jnp.concatenate([xbits, bits], axis=0),
+        )
     xq = curve.neg(fp2, tuple(c[:B] for c in both))
     r_sig = tuple(c[B:] for c in both)
-    sub_ok = (
-        curve.eq(fp2, _psi_jacobian(sig_pts), xq)
-        | curve.is_infinity(fp2, sig_pts)
-        | ~set_mask
-    )
-    subgroup_ok = jnp.all(sub_ok)
+    with jax.named_scope("psi_check"):
+        sub_ok = (
+            curve.eq(fp2, _psi_jacobian(sig_pts), xq)
+            | curve.is_infinity(fp2, sig_pts)
+            | ~set_mask
+        )
+        subgroup_ok = jnp.all(sub_ok)
 
-    r_pk = curve.scalar_mul_bits(fp, agg_pk, bits)
+    with jax.named_scope("g1_ladder"):
+        r_pk = curve.scalar_mul_bits(fp, agg_pk, bits)
     inf2 = curve.infinity(fp2)
     r_sig = curve.select(
         fp2, set_mask, r_sig,
@@ -360,6 +373,7 @@ def _stage2_fn(pk_xy, pk_mask, sig_xy, rand_bits, set_mask):
     return pk_x, pk_y, pk_inf, acc_x, acc_y, acc_inf, subgroup_ok & ~agg_inf_bad
 
 
+@scoped("bls.stage3")
 def _stage3_fn(pk_x, pk_y, pk_inf, msg_aff_x, msg_aff_y, msg_aff_inf,
                acc_x, acc_y, acc_inf):
     """The multi-pairing decision over B+1 lanes."""
@@ -374,6 +388,7 @@ def _stage3_fn(pk_x, pk_y, pk_inf, msg_aff_x, msg_aff_y, msg_aff_inf,
     )
 
 
+@scoped("bls.gather")
 def _gather_fn(table, agg, pk_idx):
     """Device-side pubkey gather (ISSUE 10): the static packer ships a
     ``(B, K)`` int32 index plane and this stage materializes the
@@ -531,8 +546,7 @@ def _run_stage(stage: str, fn, *args):
         shard,
         tuple((tuple(a.shape), str(a.dtype)) for a in args),
     )
-    # shard attr: tools/trace_report.py groups device-stage spans into
-    # per-shard chrome lanes (ISSUE 12)
+    # shard attr: the dp mesh device the stage ran on
     with tracing.span(f"bls.{stage}", fp_impl=impl, shard=shard):
         t0 = time.perf_counter()
         out = jax.block_until_ready(fn(*args))
@@ -678,9 +692,11 @@ def _staged_verify(
             "stage2", _stage2, pk_xy, pk_mask, sig_xy, rand_bits, set_mask
         )
         pk_x, pk_y, pk_inf, acc_x, acc_y, acc_inf, flags_ok = outs
-        msg_aff_x = jnp.take(mx, msg_idx, axis=0)
-        msg_aff_y = jnp.take(my, msg_idx, axis=0)
-        msg_aff_inf = jnp.take(minf, msg_idx, axis=0)
+        # eager per-lane message gathers between the stage programs
+        with tracing.span("bls.stage3_inputs"):
+            msg_aff_x = jnp.take(mx, msg_idx, axis=0)
+            msg_aff_y = jnp.take(my, msg_idx, axis=0)
+            msg_aff_inf = jnp.take(minf, msg_idx, axis=0)
         pair_ok, s3, f3 = _run_stage(
             "stage3", _stage3,
             pk_x, pk_y, pk_inf, msg_aff_x, msg_aff_y, msg_aff_inf,
@@ -692,9 +708,11 @@ def _staged_verify(
         # row per pack, raise or not, and never a stale staged row
         transfer_ledger.commit_verify(None, d2h_bytes=0)
         raise
-    out = pair_ok & flags_ok & jnp.all(sig_ok | ~set_mask)
-    # every stage output is already synced, so the verdict read is free
-    verdict = bool(out)
+    with tracing.span("bls.verdict"):
+        out = pair_ok & flags_ok & jnp.all(sig_ok | ~set_mask)
+        # every stage output is already synced, so the verdict read is
+        # one small eager reduction and its read-back
+        verdict = bool(out)
     geometry = {
         "b": int(pk_xy.shape[0]),
         "k": int(pk_xy.shape[1]),

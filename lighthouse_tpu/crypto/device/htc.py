@@ -32,6 +32,7 @@ from ..cpu.fields import Fq, Fq2
 from ..cpu.pairing import PSI_CX, PSI_CY
 from ..params import ISO3_A, ISO3_B, ISO3_Z, P, X
 from . import curve, fp, fp2
+from .scopes import scoped
 
 # ---------------------------------------------------------------------------
 # Constants (host-derived, embedded as device arrays)
@@ -78,6 +79,7 @@ def sgn0(x):
     return jnp.where(zero0, sign1, sign0)
 
 
+@scoped("sqrt")
 def sqrt(x):
     """Batched Fp2 square root -> (root, is_square). ``root`` is valid
     only where ``is_square``; x == 0 gives (0, True)."""
@@ -99,6 +101,7 @@ def sqrt(x):
 # Simplified SWU on E2' (batched, branch-free)
 # ---------------------------------------------------------------------------
 
+@scoped("sswu")
 def sswu_pre(u):
     """Pre-sqrt half of simplified SWU: u -> (x1, x2, g) where
     ``g = [gx1, gx2]`` stacked on axis -3 awaits ONE sqrt ladder. Split
@@ -129,6 +132,7 @@ def sswu_pre(u):
     return x1, x2, jnp.stack([gx1, gx2], axis=-3)
 
 
+@scoped("sswu")
 def sswu_post(u, x1, x2, roots, ok):
     """Post-sqrt half: candidate roots -> affine (x, y) with the RFC 9380
     sign rule. ``roots``/``ok`` are sqrt outputs of ``sswu_pre``'s g."""
@@ -241,6 +245,7 @@ def clear_cofactor(pt):
 # The batched map: u values -> G2 Jacobian points
 # ---------------------------------------------------------------------------
 
+@scoped("map_to_g2")
 def map_to_g2_post(u, x1, x2, roots, ok):
     """Post-sqrt remainder of the RO map: SSWU sign-pick, isogeny, the
     count-axis add, cofactor clearing. ``roots/ok`` are sqrt outputs of

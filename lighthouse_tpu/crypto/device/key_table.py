@@ -64,10 +64,12 @@ every caller.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import os
 import random
 import threading
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -78,6 +80,7 @@ from ...utils import (
     metrics,
     slot_clock,
     slot_ledger,
+    tracing,
 )
 
 # limbs per field element; pinned == fp.NL by test (this module must not
@@ -193,6 +196,30 @@ _AGG_EVENTS = metrics.counter_vec(
     "last resort)",
     ("event",),
 )
+_RESOLVE_SECONDS = metrics.histogram_vec(
+    "bls_device_key_table_resolve_seconds",
+    "resolve_sets wall time by phase: lookup (per-key index "
+    "resolution), aggregates (aggregate-cache consult, seen counts and "
+    "slot-ledger sightings), agg_sum (host EC sums + pack_g1 of insert "
+    "candidates), agg_upload (one aggregate row's device writes), total "
+    "(the whole call, lock waits included). Each phase is also the "
+    "trace span key_table.<phase> (the whole call: key_table.resolve)",
+    ("phase",),
+)
+RESOLVE_PHASES = ("lookup", "aggregates", "agg_sum", "agg_upload")
+_RESOLVE_CHILD = {
+    p: _RESOLVE_SECONDS.with_labels(p) for p in RESOLVE_PHASES + ("total",)
+}
+
+
+@contextlib.contextmanager
+def _phase(name: str):
+    """One phase of :meth:`DeviceKeyTable.resolve_sets`: a trace span
+    and an observation of ``bls_device_key_table_resolve_seconds``."""
+    t0 = time.perf_counter()
+    with tracing.span(f"key_table.{name}"):
+        yield
+    _RESOLVE_CHILD[name].observe(time.perf_counter() - t0)
 
 
 # ---------------------------------------------------------------------------
@@ -623,7 +650,18 @@ class DeviceKeyTable:
         Shipping-path accounting (``sets_total{indexed|collapsed}``) is
         the DISPATCHER's job via :meth:`count_shipped` — one commit
         point, once the batch is definitely taking the indexed path.
-        Only the ``raw`` fallback is counted here (it is final)."""
+        Only the ``raw`` fallback is counted here (it is final).
+
+        Timed by phase (``bls_device_key_table_resolve_seconds`` and the
+        ``key_table.*`` trace spans)."""
+        t0 = time.perf_counter()
+        try:
+            with tracing.span("key_table.resolve", sets=len(sets)):
+                return self._resolve_sets(sets)
+        finally:
+            _RESOLVE_CHILD["total"].observe(time.perf_counter() - t0)
+
+    def _resolve_sets(self, sets):
         with self._lock:
             if not self._dev:
                 return None
@@ -655,17 +693,18 @@ class DeviceKeyTable:
                     if not self._evict_stale_locked(cur_epoch, journal=True):
                         self._reset_aggregates_locked(journal=True)
             resolved: List[List[int]] = []
-            for _sig, pks, _msg in sets:
-                idxs = []
-                for p in pks:
-                    i = self._point_ids.get(id(p))
-                    if i is None:
-                        n = len(sets)
-                        self._sets["raw"] += n
-                        _SETS.with_labels("raw").inc(n)
-                        return None
-                    idxs.append(i)
-                resolved.append(idxs)
+            with _phase("lookup"):
+                for _sig, pks, _msg in sets:
+                    idxs = []
+                    for p in pks:
+                        i = self._point_ids.get(id(p))
+                        if i is None:
+                            n = len(sets)
+                            self._sets["raw"] += n
+                            _SETS.with_labels("raw").inc(n)
+                            return None
+                        idxs.append(i)
+                    resolved.append(idxs)
             # the batch is fully resident — NOW consult the aggregate
             # cache: hits take their slot, repeat tuples become insert
             # candidates (sum computed OUTSIDE the lock below). Hits
@@ -676,63 +715,50 @@ class DeviceKeyTable:
             hits: Dict[int, int] = {}       # set position -> RAW agg slot
             miss_positions: Dict[bytes, List[int]] = {}
             cand_keys: Dict[bytes, list] = {}  # key -> pks, ONE sum per key
-            if self.max_aggregates:
-                for j, (idxs, (_sig, pks, _msg)) in enumerate(
-                    zip(resolved, sets)
-                ):
-                    if len(idxs) <= 1:
-                        continue
-                    key = self._agg_key(idxs)
-                    slot = self._agg_slots.get(key, -1)
-                    if slot is None:
-                        continue  # known-uncacheable (sum is infinity)
-                    if slot >= 0:
-                        self._agg_hits += 1
-                        _AGG_EVENTS.with_labels("hit").inc()
-                        # chain-time (ISSUE 17): a collapsed K=1 row
-                        # served this committee — the numerator of the
-                        # per-epoch first-sighting dial
-                        slot_ledger.note_committee_sighting("hit")
-                        hits[j] = slot
-                        continue
-                    _AGG_EVENTS.with_labels("miss").inc()
-                    # first sighting: the host EC sum territory — the
-                    # denominator's other half (first + hits = committee
-                    # sightings, conservation-pinned)
-                    slot_ledger.note_committee_sighting("first")
-                    miss_positions.setdefault(key, []).append(j)
-                    if len(self._agg_seen) >= _AGG_SEEN_CAP:
-                        self._agg_seen.clear()
-                    seen = self._agg_seen.get(key, 0) + 1
-                    self._agg_seen[key] = seen
-                    if seen >= self.agg_min_repeats:
-                        # dedup by key: N repeats of one tuple in one
-                        # batch pay ONE host sum, and the slot applies
-                        # to every position below
-                        cand_keys.setdefault(key, list(pks))
+            if self.max_aggregates and any(len(x) > 1 for x in resolved):
+                with _phase("aggregates"):
+                    for j, (idxs, (_sig, pks, _msg)) in enumerate(
+                        zip(resolved, sets)
+                    ):
+                        if len(idxs) <= 1:
+                            continue
+                        key = self._agg_key(idxs)
+                        slot = self._agg_slots.get(key, -1)
+                        if slot is None:
+                            continue  # known-uncacheable (sum is infinity)
+                        if slot >= 0:
+                            self._agg_hits += 1
+                            _AGG_EVENTS.with_labels("hit").inc()
+                            # chain-time: a collapsed K=1 row
+                            # served this committee — the numerator of the
+                            # per-epoch first-sighting dial
+                            slot_ledger.note_committee_sighting("hit")
+                            hits[j] = slot
+                            continue
+                        _AGG_EVENTS.with_labels("miss").inc()
+                        # first sighting: the host EC sum territory — the
+                        # denominator's other half (first + hits = committee
+                        # sightings, conservation-pinned)
+                        slot_ledger.note_committee_sighting("first")
+                        miss_positions.setdefault(key, []).append(j)
+                        if len(self._agg_seen) >= _AGG_SEEN_CAP:
+                            self._agg_seen.clear()
+                        seen = self._agg_seen.get(key, 0) + 1
+                        self._agg_seen[key] = seen
+                        if seen >= self.agg_min_repeats:
+                            # dedup by key: N repeats of one tuple in one
+                            # batch pay ONE host sum, and the slot applies
+                            # to every position below
+                            cand_keys.setdefault(key, list(pks))
             gen = self._agg_gen
         # host EC summation + packing WITHOUT the lock: a 512-member
         # sync-committee sum is hundreds of pure-Python point adds, and
         # holding the table lock for it would serialize every verifier
         # thread and the admission listener behind host arithmetic
         prepared: List[Tuple[bytes, Optional[np.ndarray]]] = []
-        for key, pks in cand_keys.items():
-            agg = pks[0]
-            for p in pks[1:]:
-                agg = agg + p
-            if agg.is_infinity():
-                # never cache: the raw path fails this set through the
-                # device agg_inf_bad screen, and a cached infinity row
-                # would instead trip the backend's infinity pre-screen —
-                # same verdict, different screen; keep ONE behavior
-                prepared.append((key, None))
-            else:
-                from . import curve
-
-                rows, _inf = curve.pack_g1([agg])
-                prepared.append(
-                    (key, np.ascontiguousarray(rows, np.int32))
-                )
+        if cand_keys:
+            with _phase("agg_sum"):
+                prepared = self._sum_candidates(cand_keys)
         collapsed = 0
         with self._lock:
             if self._agg_gen != gen:
@@ -771,11 +797,12 @@ class DeviceKeyTable:
                         # holds. The seen count is KEPT: after a region
                         # reset an evicted hot tuple re-inserts on its
                         # very next sighting
-                        for s in list(self._agg_dev):
-                            self._agg_dev[s] = self._write_rows(
-                                self._agg_dev[s], slot, row,
-                                device=self._device_of(s),
-                            )
+                        with _phase("agg_upload"):
+                            for s in list(self._agg_dev):
+                                self._agg_dev[s] = self._write_rows(
+                                    self._agg_dev[s], slot, row,
+                                    device=self._device_of(s),
+                                )
                         self._agg_slots[key] = slot
                         self._agg_epochs[key] = cur_epoch
                         self._agg_resident += 1
@@ -812,6 +839,30 @@ class DeviceKeyTable:
             dev = self._dev[shard]
             agg_dev = self._agg_dev.get(shard)
         return resolved, dev, agg_dev, collapsed
+
+    @staticmethod
+    def _sum_candidates(cand_keys):
+        """Host EC sum and limb packing of each insert candidate:
+        ``[(key, row or None)]``, None for a sum at infinity."""
+        from . import curve
+
+        prepared: List[Tuple[bytes, Optional[np.ndarray]]] = []
+        for key, pks in cand_keys.items():
+            agg = pks[0]
+            for p in pks[1:]:
+                agg = agg + p
+            if agg.is_infinity():
+                # never cache: the raw path fails this set through the
+                # device agg_inf_bad screen, and a cached infinity row
+                # would instead trip the backend's infinity pre-screen —
+                # same verdict, different screen; keep ONE behavior
+                prepared.append((key, None))
+            else:
+                rows, _inf = curve.pack_g1([agg])
+                prepared.append(
+                    (key, np.ascontiguousarray(rows, np.int32))
+                )
+        return prepared
 
     def covers_sets(self, sets) -> bool:
         """jax-free eligibility predicate for the flush planner: would

@@ -46,6 +46,7 @@ from jax import lax
 
 from ..params import P, R, X
 from . import curve, fp, fp2, tower
+from .scopes import scoped
 
 X_ABS = -X  # 0xd201000000010000, the positive BLS parameter
 
@@ -207,6 +208,7 @@ def _dbl_step_fused(T, xP, yP):
 _XBITS = np.array([int(b) for b in bin(X_ABS)[2:]], np.int32)
 
 
+@scoped("miller_loop")
 def miller_loop(g1_aff, g2_aff):
     """f_{|x|,Q}(P) conjugated (negative parameter), batched.
 
@@ -307,6 +309,7 @@ def _conj_exp(f, e: int):
     return tower.conjugate(_exp_pos(f, -e))
 
 
+@scoped("final_exp")
 def final_exponentiation(f):
     """f^((p^12-1)/r), batched, exact. Easy part then the machine-checked
     x-chain. Used where the VALUE matters (oracle-parity pairing tests);
@@ -372,6 +375,7 @@ def _multiexp_bits() -> np.ndarray:
 _MULTIEXP_IDX = _multiexp_bits()
 
 
+@scoped("final_exp")
 def final_exp_is_one(f):
     """True iff final_exponentiation(f) == 1, via the 3h multi-exp."""
     t = _easy_part(f)

@@ -2,15 +2,25 @@
 
 The metrics registry answers "how much, how often"; this module answers
 "where did THIS batch's wall-clock go": nested, attributed, thread-aware
-timed spans exportable as chrome://tracing JSON (load the file at
-``chrome://tracing`` or https://ui.perfetto.dev). ``tools/trace_report.py``
-drives a staged device BLS verify under tracing and writes the file.
+timed spans with two sinks:
+
+* the Python buffer, exportable as chrome://tracing JSON (load the file
+  at ``chrome://tracing`` or https://ui.perfetto.dev), recorded while
+  :func:`enable` is on;
+* the ``jax.profiler`` trace: while a profiler session records, every
+  span is also a profiler TraceMe of the same name with its attrs as
+  arguments, so the program's spans land in the ``.xplane.pb`` on the
+  device operations' clock. ``tools/trace_report.py`` drives a staged
+  device BLS verify under both and writes both files.
 
 Design constraints (the hot path keeps its instrumentation always-on):
 
 * DISABLED is the default and must cost well under 1 microsecond per
   enter/exit — ``span()`` returns a shared no-op context manager without
-  allocating a span object (the zgate4 micro-check pins this).
+  allocating a span object (the zgate4 micro-check pins this). The
+  profiler check is one ``TraceMe.is_enabled()`` call, looked up only
+  once ``jaxlib._profiler`` is imported: this module never imports jax,
+  and before jaxlib is loaded no session can be recording.
 * Enabled recording is thread-safe: spans nest per-thread via a
   thread-local stack; completed spans append to a bounded global buffer
   under one lock (two appends per span, no per-event I/O).
@@ -25,6 +35,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List
@@ -39,6 +50,25 @@ _thread_names: Dict[int, str] = {}
 _t0 = time.perf_counter()
 
 _tls = threading.local()
+
+# ``jaxlib._profiler.TraceMe`` once jaxlib is loaded (see _probe)
+_TraceMe = None
+
+
+def _probe() -> bool:
+    """Whether a ``jax.profiler`` session is recording TraceMes: False
+    while jaxlib is not loaded; once it is, rebinds ``_profiling`` to
+    ``TraceMe.is_enabled`` itself, so the check costs one native call."""
+    global _TraceMe, _profiling
+    mod = sys.modules.get("jaxlib._profiler")
+    if mod is None:
+        return False
+    _TraceMe = mod.TraceMe
+    _profiling = _TraceMe.is_enabled
+    return _profiling()
+
+
+_profiling = _probe
 
 
 class _NoopSpan:
@@ -60,25 +90,50 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "t0")
+    """One span, recorded to the buffer (``record``) and/or emitted as a
+    profiler TraceMe (``traceme``, the entered TraceMe or None)."""
 
-    def __init__(self, name: str, attrs: dict):
+    __slots__ = ("name", "attrs", "t0", "record", "traceme")
+
+    def __init__(self, name: str, attrs: dict, record: bool, profile: bool):
         self.name = name
         self.attrs = attrs
+        self.record = record
+        self.traceme = (
+            _TraceMe(name, **{k: _jsonable(v) for k, v in attrs.items()})
+            if profile
+            else None
+        )
 
     def set(self, **attrs) -> None:
         """Attach attributes discovered mid-span (e.g. the verdict)."""
         self.attrs.update(attrs)
+        if self.traceme is not None:
+            self.traceme.set_metadata(
+                **{k: _jsonable(v) for k, v in attrs.items()}
+            )
 
     def __enter__(self):
-        stack = getattr(_tls, "stack", None)
-        if stack is None:
-            stack = _tls.stack = []
-        stack.append(self.name)
-        self.t0 = time.perf_counter()
+        if self.traceme is not None:
+            self.traceme.__enter__()
+        if self.record:
+            stack = getattr(_tls, "stack", None)
+            if stack is None:
+                stack = _tls.stack = []
+            stack.append(self.name)
+            self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        if self.record:
+            self._record(exc_type)
+        if self.traceme is not None:
+            if exc_type is not None:
+                self.traceme.set_metadata(error=exc_type.__name__)
+            self.traceme.__exit__(exc_type, exc, tb)
+        return False
+
+    def _record(self, exc_type) -> None:
         t1 = time.perf_counter()
         stack = _tls.stack
         stack.pop()
@@ -107,7 +162,6 @@ class _Span:
                     _thread_names[tid] = threading.current_thread().name
             else:
                 _dropped += 1
-        return False
 
 
 def _jsonable(v):
@@ -117,10 +171,12 @@ def _jsonable(v):
 def span(name: str, **attrs):
     """Context manager timing one named region; nests within the
     enclosing span of the same thread. ``attrs`` become chrome-trace
-    ``args``. When tracing is disabled this is a shared no-op."""
-    if not _enabled:
+    ``args`` and profiler TraceMe arguments. With the buffer disabled
+    and no profiler session recording this is a shared no-op."""
+    profile = _profiling()
+    if not (_enabled or profile):
         return _NOOP
-    return _Span(name, attrs)
+    return _Span(name, attrs, _enabled, profile)
 
 
 def enabled() -> bool:

@@ -279,6 +279,61 @@ def test_aggregate_collapse_on_repeat_and_region_reset():
     assert len(r5[0]) in (1, 3)
 
 
+def test_resolve_seconds_observe_the_phases_each_call_runs():
+    """bls_device_key_table_resolve_seconds{phase}: every call observes
+    total; single-key sets only the lookup; a committee's sightings
+    the aggregate-cache consult, and its repeat the host sum and the
+    row upload too. Each phase is a key_table.* span inside
+    key_table.resolve."""
+    from lighthouse_tpu.utils import tracing
+
+    sks, cache = _wrapper_cache(4)
+    t = kt.DeviceKeyTable(cache, max_aggregates=2)
+    t.sync(reason="startup")
+    phases = kt.RESOLVE_PHASES + ("total",)
+
+    def resolve(sets):
+        before = {p: kt._RESOLVE_CHILD[p].snapshot()[:2] for p in phases}
+        assert t.resolve_sets(sets) is not None
+        after = {p: kt._RESOLVE_CHILD[p].snapshot()[:2] for p in phases}
+        seconds = {p: after[p][1] - before[p][1] for p in phases}
+        assert all(v >= 0 for v in seconds.values())
+        assert sum(seconds[p] for p in kt.RESOLVE_PHASES) <= seconds["total"]
+        return {p: after[p][0] - before[p][0] for p in phases}
+
+    assert resolve(_sets_for(sks, cache)) == {
+        "lookup": 1, "aggregates": 0, "agg_sum": 0, "agg_upload": 0,
+        "total": 1,
+    }
+    committee = _sets_for(sks, cache, singles=[], committee=[0, 1, 2])
+    assert resolve(committee) == {
+        "lookup": 1, "aggregates": 1, "agg_sum": 0, "agg_upload": 0,
+        "total": 1,
+    }
+    was = tracing.enabled()
+    tracing.enable()
+    tracing.clear()
+    try:
+        assert resolve(committee) == {
+            "lookup": 1, "aggregates": 1, "agg_sum": 1, "agg_upload": 1,
+            "total": 1,
+        }
+        spans = {e["name"]: e["args"] for e in tracing.events()}
+    finally:
+        tracing.clear()
+        if not was:
+            tracing.disable()
+    assert spans["key_table.resolve"] == {"sets": 1}
+    for p in kt.RESOLVE_PHASES:
+        assert spans[f"key_table.{p}"]["parent"] == "key_table.resolve"
+    # a batch with a key the table does not hold still times the call
+    other, _ = _wrapper_cache(1, seed=9000)
+    n0 = kt._RESOLVE_CHILD["total"].snapshot()[0]
+    assert t.resolve_sets(_sets_for(other, types.SimpleNamespace(
+        pubkeys=[other[0].public_key()]))) is None
+    assert kt._RESOLVE_CHILD["total"].snapshot()[0] == n0 + 1
+
+
 def test_mid_batch_region_full_never_recycles_held_slots():
     """Regression (review round 4): a batch [cached-committee-A,
     insert-hungry-committee-B] with a FULL 1-slot region must not

@@ -526,43 +526,3 @@ def test_pipeline_report_cli_model_mode(tmp_path):
     health = tmp_path / "health.json"
     health.write_text(json.dumps({"data": {"pipeline": pp.summary()}}))
     assert pipeline_report.main(["--health-json", str(health)]) == 0
-
-
-def test_trace_report_device_lanes_and_bubble_slices():
-    """add_device_lanes groups device-stage spans by shard onto
-    synthetic lanes and draws the gaps as bubble:<cause> slices labeled
-    by dominant host-span overlap."""
-    from tools.trace_report import LANE_TID_BASE, add_device_lanes
-
-    def ev(name, ts, dur, tid=1, **args):
-        return {"name": name, "ph": "X", "ts": ts, "dur": dur,
-                "pid": 42, "tid": tid, "args": args}
-
-    trace = {"traceEvents": [
-        ev("bls.stage1", 0.0, 100.0, shard=0),
-        ev("bls.pack", 150.0, 800.0),            # host pack in the gap
-        ev("bls.stage2", 1000.0, 100.0, shard=0),
-        ev("bls.stage1", 0.0, 50.0, tid=2, shard=1),
-        ev("bls.stage2", 100.0, 50.0, tid=2, shard=1),  # gap misses the pack
-    ]}
-    info = add_device_lanes(trace)
-    assert info["lanes"] == 2 and info["source"] == "device_stage"
-    assert info["bubbles"] == 2
-    lanes = [e for e in trace["traceEvents"]
-             if e.get("tid", 0) >= LANE_TID_BASE]
-    names = {e["tid"]: set() for e in lanes}
-    for e in lanes:
-        names[e["tid"]].add(e["name"])
-    assert "bubble:pack" in names[LANE_TID_BASE]       # pack overlapped
-    assert "bubble:other" in names[LANE_TID_BASE + 1]  # nothing overlapped
-    metas = [e for e in lanes if e.get("ph") == "M"]
-    assert {m["args"]["name"] for m in metas} == {
-        "device shard 0", "device shard 1",
-    }
-    # sub_batch fallback when no device-stage spans exist (stub replay)
-    trace2 = {"traceEvents": [
-        ev("scheduler.sub_batch", 0.0, 100.0, shard=None),
-        ev("scheduler.sub_batch", 400.0, 100.0, shard=None),
-    ]}
-    info2 = add_device_lanes(trace2)
-    assert info2 == {"lanes": 1, "bubbles": 1, "source": "sub_batch"}

@@ -872,3 +872,30 @@ def test_disabled_span_costs_under_one_microsecond():
     finally:
         if was:
             tracing.enable()
+
+
+def test_disabled_span_with_jaxlib_loaded_costs_under_one_microsecond():
+    """The off path once jaxlib is imported: one profiler-state check
+    (``TraceMe.is_enabled()``) with no session recording, still no span
+    object and no TraceMe built."""
+    import jaxlib._profiler  # noqa: F401
+
+    was = tracing.enabled()
+    tracing.disable()
+    try:
+        n = 20_000
+        span = tracing.span
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                with span("zgate4.noop", shard=0):
+                    pass
+            best = min(best, (time.perf_counter() - t0) / n)
+        assert best < 1e-6, (
+            f"disabled span with jaxlib loaded costs {best * 1e9:.0f} ns "
+            f"— the profiler check must stay off the allocation path"
+        )
+    finally:
+        if was:
+            tracing.enable()

@@ -29,12 +29,13 @@ report instead of stage quantiles:
     python tools/trace_report.py --replay /tmp/flood.jsonl \\
         --time-scale 0.5 -o /tmp/replay_trace.json
 
-Both modes add PER-SHARD DEVICE LANES (ISSUE 12): device-stage spans
-(or, for stub replays, ``scheduler.sub_batch`` spans) are mirrored onto
-one synthetic timeline lane per dp shard, with the idle gaps between
-them drawn as explicit ``bubble:<cause>`` slices — the chrome view of
-the pipeline profiler's ``bls_device_bubble_seconds_total`` counters
-(docs/OBSERVABILITY.md, pipeline section).
+The staged-verify mode also records a ``jax.profiler`` trace of its
+run into ``--profile-dir`` (``create_perfetto_trace=True``): the real
+device operations, named by the programs' scopes (``bls.stage3`` /
+``miller_loop`` ...), on one clock with the program's spans, which the
+profiler records beside its own (``utils/tracing.py``). Open its
+``perfetto_trace.json.gz`` at https://ui.perfetto.dev, or the directory
+with TensorBoard's profile plugin.
 """
 
 from __future__ import annotations
@@ -46,115 +47,25 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# ---------------------------------------------------------------------------
-# Per-shard device lanes (ISSUE 12): before the pipeline profiler, every
-# flush/sub_batch/stage span rendered on its host THREAD's timeline — a
-# 2-chip replay read as one interleaved lane and a device idle gap was
-# invisible. These helpers group the device-side spans by their `shard`
-# attribute onto synthetic per-shard lanes and draw the gaps between
-# consecutive device spans as explicit `bubble:<cause>` slices, so the
-# chrome view shows occupancy per chip at a glance. The cause label is a
-# trace-local approximation (dominant overlap with host pack /
-# compile-fallback spans); the exact attribution is the profiler's
-# `bls_device_bubble_seconds_total{shard,cause}` counters.
-# ---------------------------------------------------------------------------
 
-LANE_TID_BASE = 1 << 20  # synthetic tids: far above real thread ids
-DEVICE_STAGE_NAMES = ("bls.gather", "bls.stage1", "bls.stage2", "bls.stage3")
-MIN_BUBBLE_US = 20.0
-
-
-def _dominant_cause(g0: float, g1: float, causes) -> str:
-    """Largest-overlap host activity inside the gap [g0, g1] µs, or
-    ``other`` when nothing overlaps — the same name the profiler's
-    cause catalogue gives the uncovered remainder, so the trace slices
-    and the counters speak one vocabulary (trace-local label; the
-    profiler counters are the exact attribution)."""
-    best, best_overlap = "other", 0.0
-    acc: dict = {}
-    for cause, a0, a1 in causes:
-        ov = min(a1, g1) - max(a0, g0)
-        if ov > 0:
-            acc[cause] = acc.get(cause, 0.0) + ov
-    for cause, ov in acc.items():
-        if ov > best_overlap:
-            best, best_overlap = cause, ov
-    return best
-
-
-def add_device_lanes(trace: dict, min_bubble_us: float = MIN_BUBBLE_US) -> dict:
-    """Augment a chrome trace IN PLACE with per-shard device lanes:
-    device-stage spans (``bls.stage*``/``bls.gather``; falls back to
-    ``scheduler.sub_batch`` for stub replays that never reach a device)
-    are mirrored onto one synthetic lane per shard, and the gaps
-    between consecutive spans on a lane become ``bubble:<cause>``
-    slices. Returns {lanes, bubbles, source}."""
-    evs = trace["traceEvents"]
-    stage = [
-        e for e in evs
-        if e.get("ph") == "X" and e.get("name") in DEVICE_STAGE_NAMES
-    ]
-    source = "device_stage"
-    if not stage:
-        stage = [
-            e for e in evs
-            if e.get("ph") == "X" and e.get("name") == "scheduler.sub_batch"
-        ]
-        source = "sub_batch"
-    lanes: dict = {}
-    for e in stage:
-        shard = e.get("args", {}).get("shard")
-        shard = 0 if shard in (None, "None") else int(shard)
-        lanes.setdefault(shard, []).append(e)
-    causes = []
-    for e in evs:
-        if e.get("ph") != "X":
-            continue
-        if e.get("name") == "bls.pack":
-            causes.append(("pack", e["ts"], e["ts"] + e["dur"]))
-        elif e.get("name") == "compile_service.fallback_verify":
-            causes.append(("compile", e["ts"], e["ts"] + e["dur"]))
-    new = []
-    n_bubbles = 0
-    for shard, sevs in sorted(lanes.items()):
-        tid = LANE_TID_BASE + shard
-        pid = sevs[0]["pid"]
-        new.append({
-            "name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
-            "args": {"name": f"device shard {shard}"},
-        })
-        sevs.sort(key=lambda e: e["ts"])
-        last_end = None
-        for e in sevs:
-            if last_end is not None and e["ts"] - last_end > min_bubble_us:
-                cause = _dominant_cause(last_end, e["ts"], causes)
-                new.append({
-                    "name": f"bubble:{cause}", "ph": "X",
-                    "ts": round(last_end, 3),
-                    "dur": round(e["ts"] - last_end, 3),
-                    "pid": pid, "tid": tid,
-                    "args": {"shard": shard, "cause": cause},
-                })
-                n_bubbles += 1
-            lane_ev = dict(e)
-            lane_ev["tid"] = tid
-            new.append(lane_ev)
-            end = e["ts"] + e["dur"]
-            last_end = end if last_end is None else max(last_end, end)
-    trace["traceEvents"] = evs + new
-    return {"lanes": len(lanes), "bubbles": n_bubbles, "source": source}
-
-
-def write_trace_with_lanes(out_path: str) -> tuple:
-    """Export the recorded spans + per-shard device lanes to
-    ``out_path``; returns (event count, lane info)."""
+def write_chrome_trace(out_path: str) -> int:
+    """Export the recorded spans to ``out_path``; returns the event
+    count."""
     from lighthouse_tpu.utils import tracing
 
-    trace = tracing.chrome_trace()
-    lane_info = add_device_lanes(trace)
-    with open(out_path, "w") as f:
-        json.dump(trace, f)
-    return len(trace["traceEvents"]), lane_info
+    return tracing.export_chrome(out_path)
+
+
+def find_perfetto_trace(profile_dir: str):
+    """The ``perfetto_trace.json.gz`` a profiler session wrote under
+    ``profile_dir`` (None if it wrote none)."""
+    import glob
+
+    found = sorted(glob.glob(
+        os.path.join(profile_dir, "**", "perfetto_trace.json.gz"),
+        recursive=True,
+    ))
+    return found[-1] if found else None
 
 
 def build_sets(n_sets: int, committee: int, n_msgs: int):
@@ -208,14 +119,13 @@ def replay_main(args) -> None:
         deadline_ms=args.deadline_ms,
         time_scale=args.time_scale,
     )
-    n, lane_info = write_trace_with_lanes(args.out)
+    n = write_chrome_trace(args.out)
     print(
         json.dumps(
             {
                 "trace": args.out,
                 "events": n,
                 "dropped": tracing.dropped(),
-                "device_lanes": lane_info,
                 "replayed": {
                     "trace_file": args.replay,
                     "name": header.get("name"),
@@ -233,6 +143,9 @@ def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("-o", "--out", default="/tmp/bls_trace.json",
                     help="chrome trace output path")
+    ap.add_argument("--profile-dir", default=None,
+                    help="jax.profiler trace directory of the staged "
+                    "verify run (default: <out>.profile)")
     ap.add_argument("--sets", type=int, default=4)
     ap.add_argument("--committee", type=int, default=2)
     ap.add_argument("--msgs", type=int, default=2)
@@ -268,24 +181,29 @@ def main(argv=None) -> None:
     tracing.enable()
     tracing.clear()
 
+    import jax
+
     from lighthouse_tpu.crypto.device.bls import TpuBackend
 
+    profile_dir = args.profile_dir or os.path.splitext(args.out)[0] + ".profile"
     sets = build_sets(args.sets, args.committee, args.msgs)
     backend = TpuBackend()
-    with tracing.span("trace_report.run", reps=args.reps):
-        for rep in range(args.reps):
-            with tracing.span("trace_report.rep", rep=rep):
-                ok = backend.verify_signature_sets(sets)
+    with jax.profiler.trace(profile_dir, create_perfetto_trace=True):
+        with tracing.span("trace_report.run", reps=args.reps):
+            for rep in range(args.reps):
+                with tracing.span("trace_report.rep", rep=rep):
+                    ok = backend.verify_signature_sets(sets)
     assert ok is True, "trace workload must verify"
 
-    n, lane_info = write_trace_with_lanes(args.out)
+    n = write_chrome_trace(args.out)
     print(
         json.dumps(
             {
                 "trace": args.out,
                 "events": n,
                 "dropped": tracing.dropped(),
-                "device_lanes": lane_info,
+                "profile_dir": profile_dir,
+                "perfetto_trace": find_perfetto_trace(profile_dir),
                 "verdict": bool(ok),
                 "stage_latency": stage_quantile_summary(),
             }
