@@ -480,29 +480,92 @@ def _bits_msb(e: int) -> np.ndarray:
     return np.array([int(b) for b in bin(e)[2:]], np.int32)
 
 
-def square_multiply(x, e: int, sq_fn, mul_fn, select_fn):
-    """Shared fixed-exponent square-and-multiply ladder (MSB-first scan).
+def _digits_msb(e: int, w: int) -> list[int]:
+    """``e`` in bijective base 2**w (digits 1..2**w), most significant
+    first. No digit is 0, so the ladder's table needs no one."""
+    base, out = 1 << w, []
+    while e:
+        d = e % base or base
+        out.append(d)
+        e = (e - d) >> w
+    return out[::-1]
 
-    Serves every pow_const in the device stack (fp/fp2/fp12) — one place
-    to fix or re-window the ladder. ``e`` must be >= 1.
+
+def _window_muls(e: int, w: int) -> int:
+    """Multiplies of the w-bit window ladder: 2**w - 1 to build the table
+    x, x^2, ..., x^(2**w), one per digit after the leading one."""
+    return (1 << w) - 1 + len(_digits_msb(e, w)) - 1
+
+
+def window_width(e: int) -> int:
+    """Window of the ladder for exponent ``e``: the ``w`` with the fewest
+    multiplies, or 1 (the bit ladder, one multiply per bit after the
+    first) where no window needs fewer. A pure function of ``e``."""
+    n = e.bit_length()
+    best = min(range(2, n.bit_length() + 1),
+               key=lambda w: _window_muls(e, w), default=1)
+    return best if best > 1 and _window_muls(e, best) < n - 1 else 1
+
+
+def ladder_counts(e: int) -> tuple[int, int]:
+    """(squarings, multiplies) that ``square_multiply`` spends on ``e``."""
+    w = window_width(e)
+    if w == 1:
+        return (e.bit_length() - 1,) * 2
+    return w * (len(_digits_msb(e, w)) - 1), _window_muls(e, w)
+
+
+def square_multiply(x, e: int, sq_fn, mul_fn):
+    """Shared fixed-exponent ladder (MSB first), for every pow_const in
+    the device stack (fp/fp2/fp12) and ``htc.f2pow``. ``e`` must be >= 1.
+
+    A fixed window of ``w = window_width(e)`` bits, run as one scan over a
+    schedule fixed at trace time. The carry is a table ``T`` with
+    ``T[i] = x^(i+1)`` for ``i < 2**w`` and the accumulator in
+    ``T[2**w]``; each step sets ``T[dst] = T[src]^(2^nsq) * T[fac]``. The
+    first ``2**w - 1`` steps fill the table (``nsq = 0``, ``fac = 0``);
+    then each base-2**w digit of ``e`` after the leading one costs ``w``
+    squarings and one multiply by the entry it indexes. ``sq_fn`` and
+    ``mul_fn`` are emitted once each. At 381 bits that is w = 5 and 107
+    multiplies in place of 380. Short exponents keep the bit ladder:
+    square, then multiply and keep it where the bit is set. Every lane
+    does the same work; x == 0 gives 0.
     """
     assert e >= 1
-    bits = _bits_msb(e)
-    if len(bits) == 1:
-        return x
+    w = window_width(e)
+    if w == 1:
+        bits = _bits_msb(e)
+        if len(bits) == 1:
+            return x
 
-    def body(acc, bit):
-        acc = sq_fn(acc)
-        acc = select_fn(bit == 1, mul_fn(acc, x), acc)
-        return acc, None
+        def bit_step(acc, bit):
+            acc = sq_fn(acc)
+            return jnp.where(bit == 1, mul_fn(acc, x), acc), None
 
-    acc, _ = lax.scan(body, x, jnp.asarray(bits[1:]))
-    return acc
+        acc, _ = lax.scan(bit_step, x, jnp.asarray(bits[1:]))
+        return acc
+
+    acc_slot = 1 << w
+    digits = _digits_msb(e, w)
+    schedule = [(j - 1, 0, 0, j) for j in range(1, acc_slot)]
+    schedule.append((digits[0] - 1, w, digits[1] - 1, acc_slot))
+    schedule += [(acc_slot, w, d - 1, acc_slot) for d in digits[2:]]
+
+    def step(t, s):
+        src, nsq, fac, dst = s
+        acc = lax.fori_loop(0, nsq, lambda _, a: sq_fn(a),
+                            lax.dynamic_index_in_dim(t, src, keepdims=False))
+        acc = mul_fn(acc, lax.dynamic_index_in_dim(t, fac, keepdims=False))
+        return lax.dynamic_update_index_in_dim(t, acc, dst, 0), None
+
+    table = jnp.broadcast_to(x, (acc_slot + 1, *x.shape))
+    table, _ = lax.scan(step, table, jnp.asarray(schedule, np.int32))
+    return table[acc_slot]
 
 
 def pow_const(x, e: int):
-    """x**e for a fixed exponent, as a scan over its bits (MSB first)."""
-    return square_multiply(x, e, sq, mul, select)
+    """x**e for a fixed exponent (the shared window ladder)."""
+    return square_multiply(x, e, sq, mul)
 
 
 def inv(x):

@@ -246,4 +246,4 @@ def select(mask, a, b):
 
 def pow_const(x, e: int):
     """x**e for a fixed Python-int exponent (shared ladder in fp)."""
-    return fp.square_multiply(x, e, sq, mul, select)
+    return fp.square_multiply(x, e, sq, mul)

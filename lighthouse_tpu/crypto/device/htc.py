@@ -61,8 +61,8 @@ X_ABS = -X
 
 
 def f2pow(x, e: int):
-    """Fp2 fixed-exponent ladder (shared square-and-multiply scan)."""
-    return fp.square_multiply(x, e, fp2.sq, fp2.mul, fp2.select)
+    """Fp2 fixed-exponent ladder (the shared window ladder in fp)."""
+    return fp.square_multiply(x, e, fp2.sq, fp2.mul)
 
 
 # ---------------------------------------------------------------------------

@@ -300,7 +300,7 @@ def _add_line_fused(T, xQ, yQ, xP, yP):
 # ---------------------------------------------------------------------------
 
 def _exp_pos(f, e: int):
-    """f^e for fixed positive e (generic square-and-multiply scan)."""
+    """f^e for fixed positive e (the shared window ladder)."""
     return tower.pow_const(f, e)
 
 

@@ -260,7 +260,7 @@ def pow_const(x, e: int):
     assert e >= 0
     if e == 0:
         return jnp.broadcast_to(ones(), x.shape).astype(jnp.int32)
-    return fp.square_multiply(x, e, sq, mul, select)
+    return fp.square_multiply(x, e, sq, mul)
 
 
 def from_fp2(a):

@@ -8,7 +8,7 @@ cannot displace functional coverage inside the tier-1 wall-clock)."""
 import numpy as np
 import pytest
 
-from lighthouse_tpu.crypto.params import P
+from lighthouse_tpu.crypto.params import P, X
 from lighthouse_tpu.crypto.device import fp
 
 
@@ -109,6 +109,56 @@ def test_pow_inv(rng):
         assert (x * i) % P == 1
     # inv(0) == 0 convention
     assert _val(fp.inv(_pack([0])))[0] == 0
+
+
+# the ladder's edge exponents: the bit ladder's (1, 2, 3 and a 5-bit
+# window's 2^w - 1, 2^w, 2^w + 1), digits at 2^w on the windowed path
+# (2^100 - 1, 2^100, 2^100 + 1), |X|, and the exponents of the Fp2 square
+# root and of Fermat inversion
+LADDER_EXPONENTS = [
+    1, 2, 3, 31, 32, 33, (1 << 100) - 1, 1 << 100, (1 << 100) + 1, -X,
+    (P - 3) // 4, (P - 1) // 2, P - 2,
+]
+
+
+def ladder_id(e):
+    return f"{e}" if e < 1000 else f"{e.bit_length()}bits-{e % 1000}"
+
+
+@pytest.mark.parametrize("e", LADDER_EXPONENTS, ids=ladder_id)
+def test_square_multiply_matches_pow(rng, e):
+    xs = _rand_elems(rng, 4) + [0, 1, P - 1]
+    got = _val(fp.square_multiply(_pack(xs), e, fp.sq, fp.mul))
+    assert got == [pow(x, e, P) for x in xs]
+
+
+def test_window_width_keeps_the_bit_ladder_for_short_exponents():
+    # a window pays 2^w - 1 table multiplies: below 8 bits none wins
+    assert [fp.window_width(e) for e in (1, 2, 3, 31, 32, 33, 127)] == [1] * 7
+    assert fp.window_width(255) == 2
+    assert fp.window_width(-X) == 3
+    assert [fp.window_width(e) for e in ((P - 3) // 4, (P - 1) // 2, P - 2)] == [5] * 3
+
+
+def test_ladder_counts():
+    # (squarings, multiplies): the bit ladder always multiplies
+    assert fp.ladder_counts(1) == (0, 0)
+    assert fp.ladder_counts(127) == (6, 6)
+    assert fp.ladder_counts(-X) == (63, 28)
+    assert fp.ladder_counts((P - 3) // 4) == (375, 106)
+    assert fp.ladder_counts((P - 1) // 2) == (375, 106)
+    assert fp.ladder_counts(P - 2) == (380, 107)
+
+
+@pytest.mark.parametrize("w", [2, 3, 5])
+def test_window_digits_spell_the_exponent(rng, w):
+    for e in [1, (1 << (4 * w)) - 1, 1 << (4 * w), P - 2, rng.randrange(1, P)]:
+        digits = fp._digits_msb(e, w)
+        assert all(1 <= d <= 1 << w for d in digits)
+        acc = 0
+        for d in digits:
+            acc = (acc << w) + d
+        assert acc == e
 
 
 def test_select():

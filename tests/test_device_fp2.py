@@ -5,10 +5,13 @@ int8 limb-split engine by ``test_zgate1_fp_impl_matrix.py`` (tail-sorted,
 see that module's docstring)."""
 
 import numpy as np
+import pytest
 
 from lighthouse_tpu.crypto.params import P
 from lighthouse_tpu.crypto.cpu.fields import Fq2
 from lighthouse_tpu.crypto.device import fp, fp2
+
+from test_device_fp import LADDER_EXPONENTS, ladder_id
 
 
 def _pack(pairs):
@@ -92,3 +95,10 @@ def test_pow_const_scale(rng):
     from lighthouse_tpu.crypto.cpu.fields import Fq
 
     assert got == [_to_pair(a.scale(Fq(k))) for a in _oracle(xs)]
+
+
+@pytest.mark.parametrize("e", LADDER_EXPONENTS, ids=ladder_id)
+def test_square_multiply_matches_oracle(rng, e):
+    xs = _rand_pairs(rng, 3) + [(0, 0), (1, 0), (0, 1)]
+    got = _val(fp.square_multiply(_pack(xs), e, fp2.sq, fp2.mul))
+    assert got == [_to_pair(a.pow(e)) for a in _oracle(xs)]

@@ -40,6 +40,32 @@ def test_sqrt_and_sgn0(rng):
         assert int(sg_vals[i]) == v.sgn0()
 
 
+@pytest.mark.parametrize("engine", [fp.IMPL_TOEPLITZ_INT32, fp.IMPL_MATMUL_INT8])
+def test_sqrt_matches_oracle(rng, engine):
+    """Same (root, ok) as the oracle's Fq2.sqrt on squares, non-squares
+    and 0, under both fp.mul engines."""
+    vals = [Fq2(Fq(0), Fq(0))]
+    while len(vals) < 9:
+        q = Fq2(Fq(rng.randrange(P)), Fq(rng.randrange(P)))
+        if len(vals) < 5:
+            q = q * q
+        if (q.sqrt() is None) == (len(vals) >= 5):
+            vals.append(q)
+    arr = jnp.asarray(np.stack([
+        np.stack([fp.int_to_limbs(v.c0.n), fp.int_to_limbs(v.c1.n)])
+        for v in vals
+    ]))
+    with fp.impl(engine):
+        roots, ok = jax.jit(lambda a: htc.sqrt(a))(arr)
+    roots = np.asarray(fp.canonical(roots))
+    for i, v in enumerate(vals):
+        want = v.sqrt()
+        assert bool(ok[i]) == (want is not None), i
+        if want is not None:
+            got = (fp.limbs_to_int(roots[i][0]), fp.limbs_to_int(roots[i][1]))
+            assert got == (want.c0.n, want.c1.n), i
+
+
 def test_map_to_g2_matches_oracle(rng):
     msgs = [bytes([rng.randrange(256) for _ in range(32)]) for _ in range(3)]
     u = jnp.asarray(htc.messages_to_u(msgs, DST))

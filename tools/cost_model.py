@@ -20,6 +20,7 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
+from lighthouse_tpu.crypto.device.fp import ladder_counts  # noqa: E402
 from lighthouse_tpu.crypto.params import P, X  # noqa: E402
 from lighthouse_tpu.utils import transfer_ledger  # noqa: E402  (jax-free)
 
@@ -37,11 +38,17 @@ G1_DBL = 8                    # curve.dbl over fp
 G2_ADD = 12 * FP2_MUL         # same formulas over fp2
 G2_DBL = 8 * FP2_MUL
 
-NBITS_P = (P - 2).bit_length()          # 381: fp.inv ladder length
-FP_INV = 2 * NBITS_P                    # sq + mul per bit (fp.pow_const scan)
-F2POW_PER_BIT = FP2_SQ + FP2_MUL        # htc.f2pow ladder step
-SQRT_ELEM = ((P - 3) // 4).bit_length() * F2POW_PER_BIT \
-    + ((P - 1) // 2).bit_length() * F2POW_PER_BIT + 6 * FP2_MUL
+
+def ladder(e: int, sq: int, mul: int) -> int:
+    """Lanes of one fp.square_multiply ladder (its fixed-window schedule)."""
+    n_sq, n_mul = ladder_counts(e)
+    return n_sq * sq + n_mul * mul
+
+
+NBITS_P = (P - 2).bit_length()          # 381: fp.inv exponent length
+FP_INV = ladder(P - 2, 1, 1)            # fp.inv: fp.pow_const window ladder
+SQRT_ELEM = ladder((P - 3) // 4, FP2_SQ, FP2_MUL) \
+    + ladder((P - 1) // 2, FP2_SQ, FP2_MUL) + 6 * FP2_MUL
 # htc.sqrt: two f2pow ladders (a1, b) + candidate muls, per batch element
 
 SCALAR64_G2 = 64 * (G2_DBL + G2_ADD)    # curve.scalar_mul_bits, 64-bit
@@ -171,7 +178,7 @@ def main() -> None:
     w(f"| **total** | **{int(c16['total']):,}** |")
     w("")
     w("Derived constants: fp.inv ladder = "
-      f"{FP_INV} lanes ({NBITS_P}-bit Fermat scan); one Fp2 sqrt element = "
+      f"{FP_INV} lanes ({NBITS_P}-bit Fermat window ladder); one Fp2 sqrt element = "
       f"{SQRT_ELEM:,} lanes (two ~381-bit ladders); 64-bit G2 scalar mul = "
       f"{SCALAR64_G2:,} lanes; Miller loop = {MILLER_PER_LANE:,} lanes/lane "
       f"({X_BITS - 1} bits x (Fp12 sq + 2 sparse-line muls + dbl + add)); "
