@@ -701,6 +701,7 @@ def estimate_capacity(
     cost_s_per_set: Optional[float] = None,
     shards: Optional[int] = None,
     publish: bool = True,
+    deadline_sets_per_sec: Optional[float] = None,
 ) -> dict:
     """One estimator pass: combine measured cost, healthy shards and
     the arrival rate into the capacity/utilization/headroom triple.
@@ -709,7 +710,12 @@ def estimate_capacity(
     modeled inputs and ``publish=False`` (the formula has exactly one
     home; a model run must not write the live gauges); anything
     unmeasured stays ``None`` and the corresponding gauge is left
-    untouched — the dial never lies."""
+    untouched — the dial never lies. ``deadline_sets_per_sec``, the
+    deadline class's demand alone, gives ``deadline_headroom_ratio``:
+    the headroom gossip leaves, which the bulk admission valve reads
+    (bulk service counts in ``headroom_ratio``, so a lone saturating
+    backfill would otherwise read the device it fills as no headroom
+    and throttle itself)."""
     source = "override"
     if cost_s_per_set is None:
         cost_s_per_set, source = measured_cost_per_set()
@@ -718,7 +724,11 @@ def estimate_capacity(
     est = None
     if cost_s_per_set and cost_s_per_set > 0:
         est = shards / cost_s_per_set
-    utilization = headroom = None
+    utilization = headroom = deadline_headroom = None
+    if est is not None and deadline_sets_per_sec is not None:
+        deadline_headroom = (
+            max(0.0, 1.0 - deadline_sets_per_sec / est) if est > 0 else 0.0
+        )
     if est is not None and arrival_sets_per_sec is not None:
         if est > 0:
             utilization = arrival_sets_per_sec / est
@@ -746,6 +756,10 @@ def estimate_capacity(
         ),
         "headroom_ratio": (
             round(headroom, 4) if headroom is not None else None
+        ),
+        "deadline_headroom_ratio": (
+            round(deadline_headroom, 4)
+            if deadline_headroom is not None else None
         ),
     }
     if publish:
@@ -806,6 +820,7 @@ def sample(now: Optional[float] = None) -> Optional[dict]:
         now = time.time()
     store = get_store()
     arrival_total: Optional[float] = None
+    deadline_total: Optional[float] = None
     bulk_served = 0.0
     with _state_lock:
         for spec in SAMPLE_FAMILIES:
@@ -838,9 +853,13 @@ def sample(now: Optional[float] = None) -> Optional[dict]:
             # feedback loop that could never un-throttle under a
             # persistent bulk submitter. The per-kind arrival SERIES
             # keeps the full demand picture (bulk included).
-            arrival_total = max(0.0, arrival_total - bulk_demand) + bulk_served
+            deadline_total = max(0.0, arrival_total - bulk_demand)
+            arrival_total = deadline_total + bulk_served
         _update_interval_shard_cost()
-    est = estimate_capacity(arrival_sets_per_sec=arrival_total)
+    est = estimate_capacity(
+        arrival_sets_per_sec=arrival_total,
+        deadline_sets_per_sec=deadline_total,
+    )
     if est["estimated_sets_per_sec"] is not None:
         store.record(
             "capacity_estimated_sets_per_sec",

@@ -10,8 +10,12 @@ decision and pauses bulk admission while either says the node is out
 of slack:
 
 * **capacity headroom** (``utils/timeseries.py``,
-  ``capacity_headroom_ratio`` = max(0, 1 − arrival/capacity)): when the
-  live estimate drops below ``floor`` (default 0.10,
+  ``deadline_headroom_ratio`` = max(0, 1 − deadline demand/capacity)):
+  the headroom the deadline class leaves. Admitted bulk service is
+  left out of it (it is in ``capacity_headroom_ratio``): bulk flushes
+  only at gossip idle, so a lone range sync that fills the device must
+  not read its own service as lost headroom and throttle itself. When
+  the live estimate drops below ``floor`` (default 0.10,
   ``LIGHTHOUSE_TPU_SCHED_BULK_HEADROOM_FLOOR``) the node is close
   enough to saturation that bulk must stop feeding the queue. The dial
   is PREDICTIVE — on a saturation ramp it crosses before the first
@@ -91,16 +95,16 @@ _THROTTLE_EVENTS = metrics.counter_vec(
 
 def _live_headroom() -> Optional[float]:
     """The default headroom feed: the capacity estimator's latest
-    ``headroom_ratio`` (None when the sampler is off or no cost has
-    been measured — 'no signal', never 'no headroom'). Lazy import so
-    this module stays cheap and jax-free at import."""
+    ``deadline_headroom_ratio`` (None when the sampler is off or no
+    cost has been measured — 'no signal', never 'no headroom'). Lazy
+    import so this module stays cheap and jax-free at import."""
     try:
         from ..utils import timeseries
 
         est = timeseries.last_estimate()
         if est is None:
             return None
-        return est.get("headroom_ratio")
+        return est.get("deadline_headroom_ratio")
     except Exception:
         return None
 

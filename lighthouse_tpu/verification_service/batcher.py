@@ -252,8 +252,7 @@ _QUEUE_WAIT = metrics.histogram(
     "wait spans linger + gossip-busy windows + throttle excursions by "
     "design and would explode this histogram's tail while gossip is "
     "perfectly healthy; bulk wait is visible in "
-    "verification_scheduler_verdict_latency_seconds{path=bulk} and the "
-    "bulk queue-depth gauge",
+    "verification_scheduler_bulk_wait_seconds",
 )
 _BISECTIONS = metrics.counter(
     "verification_scheduler_bisections_total",
@@ -375,6 +374,22 @@ _BULK_SHED = metrics.counter_vec(
     "order: bulk sheds first, self-paced, never onto gossip's flush "
     "thread)",
     ("kind",),
+)
+_BULK_CHUNKS = metrics.counter_vec(
+    "verification_scheduler_bulk_chunks_total",
+    "bulk submissions backend_verify_bulk carved out of its callers' "
+    "segments (chunks of at most bulk_flush_sets sets) per caller kind "
+    "— one segment of N sets submits ceil(N / bulk_flush_sets)",
+    ("kind",),
+)
+_BULK_WAIT = metrics.histogram_vec(
+    "verification_scheduler_bulk_wait_seconds",
+    "submit-to-flush wait per queued BULK-class submission, per caller "
+    "kind: linger, gossip-busy windows, throttle excursions and the "
+    "flushes of the chunks queued ahead of it (the deadline class's "
+    "wait is verification_scheduler_queue_wait_seconds)",
+    ("kind",),
+    buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 20.0, 40.0),
 )
 _DEADLINE_MISSES = metrics.counter_vec(
     "verification_scheduler_deadline_misses_total",
@@ -998,6 +1013,7 @@ class VerificationScheduler:
             _SETS_TOTAL.with_labels(s.kind).inc(len(s.sets))
             if qos == "bulk":
                 _BULK_SETS.with_labels(s.kind).inc(len(s.sets))
+                _BULK_WAIT.with_labels(s.kind).observe(now - s.submitted_at)
         if qos == "bulk":
             self._bulk_flushes += 1
             self._bulk_sets_flushed += n_sets
@@ -1655,18 +1671,28 @@ def backend_verify_bulk(chain, sets, kind: str) -> bool:
     at most ONE in-flight bulk chunk). All chunks are submitted before
     any result is awaited (they fuse/pipeline at gossip idle), every
     future is consumed, and the all() verdict matches the single batch
-    call's."""
+    call's. The whole segment is one ``scheduler.bulk_segment`` span;
+    its chunks count in ``verification_scheduler_bulk_chunks_total``."""
     sched = scheduler_of(chain)
     if sched is None:
         return bls.verify_signature_sets(sets)
     sets = list(sets)
-    if not sets:
-        # matches verify_signature_sets([]) == False via the
-        # scheduler's empty-submission path
-        return sched.submit(sets, kind, qos="bulk").result()
     chunk = max(1, int(sched.bulk_flush_sets))
-    futs = [
-        sched.submit(sets[i:i + chunk], kind, qos="bulk")
-        for i in range(0, len(sets), chunk)
-    ]
-    return all([f.result() for f in futs])
+    n_chunks = -(-len(sets) // chunk)
+    with tracing.span(
+        "scheduler.bulk_segment", kind=kind, n_sets=len(sets),
+        n_chunks=n_chunks,
+    ) as sp:
+        if not sets:
+            # matches verify_signature_sets([]) == False via the
+            # scheduler's empty-submission path
+            ok = sched.submit(sets, kind, qos="bulk").result()
+        else:
+            _BULK_CHUNKS.with_labels(kind).inc(n_chunks)
+            futs = [
+                sched.submit(sets[i:i + chunk], kind, qos="bulk")
+                for i in range(0, len(sets), chunk)
+            ]
+            ok = all([f.result() for f in futs])
+        sp.set(verdict=ok)
+    return ok

@@ -709,6 +709,54 @@ def test_utilization_numerator_excludes_parked_bulk_demand():
         timeseries.reset()
 
 
+def test_lone_saturating_bulk_does_not_throttle_itself(
+    recorder, monkeypatch,
+):
+    """A lone range sync that fills the device (gossip idle) reads the
+    device's headroom as nearly gone — its own admitted service is in
+    the utilization numerator — but the valve reads the headroom the
+    DEADLINE class leaves, so it stays open; deadline demand at the
+    same rate closes it. Before, the valve read ``headroom_ratio`` and
+    the lone caller throttled itself once per sampling interval."""
+    from lighthouse_tpu.utils import timeseries
+
+    arrivals = metrics.counter_vec(
+        "verification_scheduler_arrival_sets_total",
+        labelnames=("kind", "path"),
+    )
+    served = metrics.counter_vec(
+        "verification_scheduler_bulk_sets_total", labelnames=("kind",),
+    )
+    arrivals.with_labels("bq_seg_gossip", "submit")
+    arrivals.with_labels("bq_seg_sync", "bulk")
+    served.with_labels("bq_seg_sync")
+    # 100 sets/s of capacity, whatever other tests left measured
+    monkeypatch.setattr(
+        timeseries, "measured_cost_per_set", lambda: (0.01, "override")
+    )
+    timeseries.reset()
+    try:
+        t0 = time.time()
+        assert timeseries.sample(now=t0) is not None  # baseline pass
+        # the segment's chunks: all submitted, 95% of capacity served
+        arrivals.with_labels("bq_seg_sync", "bulk").inc(4192)
+        served.with_labels("bq_seg_sync").inc(95)
+        est = timeseries.sample(now=t0 + 1.0)
+        assert est["headroom_ratio"] == pytest.approx(0.05)
+        assert est["deadline_headroom_ratio"] == pytest.approx(1.0)
+        ctl = BulkAdmissionController(min_interval_s=0.0)
+        assert ctl.evaluate() is True
+        assert not _events("bulk_throttle")
+        # the same rate of deadline-class demand leaves no headroom
+        arrivals.with_labels("bq_seg_gossip", "submit").inc(95)
+        est = timeseries.sample(now=t0 + 2.0)
+        assert est["deadline_headroom_ratio"] == pytest.approx(0.05)
+        assert ctl.evaluate() is False
+        assert len(_events("bulk_throttle")) == 1
+    finally:
+        timeseries.reset()
+
+
 def test_lockstep_models_bulk_queue_deterministically():
     evs = traffic.bulk_backfill_under_gossip(duration_s=4.0, seed=7)
     assert any(e.get("qos") == "bulk" for e in evs)
